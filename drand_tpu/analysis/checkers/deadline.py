@@ -3,8 +3,8 @@
 r06 wedged for 2h07m on 42 hung probes: each probe's subprocess had no
 timeout, the caller had no deadline parameter to thread, and nothing
 above it could bound the wait without killing the process.  The fix
-pattern (net/client.py `_unary`, resilience budgets, accel.probe_backend)
-is always the same shape — a `timeout=`/`deadline=` parameter that
+pattern (net/client.py `_unary`, resilience budgets) is always the
+same shape — a `timeout=`/`deadline=` parameter that
 REACHES the blocking primitive — and this checker enforces that shape
 statically, the compile-time half of ROADMAP item 1's fail-fast
 preflight.

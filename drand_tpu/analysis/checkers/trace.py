@@ -16,8 +16,8 @@ A second, host-level pass (`sync-in-loop`, ISSUE 10) covers crypto/
 orchestration code: synchronous device readback (np.asarray / bool() /
 int() / float() / .item() / .block_until_ready() / jax.device_get) on a
 device-produced value INSIDE a per-chunk for/while loop serializes the
-whole stream — every iteration pays a full interconnect round trip (the
-r5 finding: ~1 RPC latency of pure stall per chunk).  Hot-path loops
+whole stream — every iteration pays a full device round trip (the r5
+finding: one dispatch latency of pure stall per chunk).  Hot-path loops
 must stay async (pack/dispatch/resolve with a depth-k window) and sync
 once per stream.  Device taint: values from `dispatch_packed`/
 `_rlc_dispatch` calls or from invoking a compiled `*_pipeline` object.

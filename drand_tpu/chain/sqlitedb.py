@@ -177,32 +177,12 @@ class SqliteStore(Store):
 
     def save_to(self, fileobj) -> None:
         """Native snapshot: the serialized sqlite image (BackupDatabase RPC,
-        chain/store.go:24 SaveTo analogue).  Connection.serialize() needs
-        Python 3.11; older runtimes snapshot through the online backup API
-        into a temp file — same bytes, one extra disk round trip."""
+        chain/store.go:24 SaveTo analogue)."""
         with self._lock:
-            if hasattr(self._conn, "serialize"):
-                # fold the WAL into the main image first, or commits since
-                # the last checkpoint would be missing from the snapshot
-                self._conn.execute("PRAGMA wal_checkpoint(TRUNCATE)")
-                fileobj.write(self._conn.serialize())
-                return
-            import os
-            import sqlite3
-            import tempfile
-            fd, tmp = tempfile.mkstemp(suffix=".db")
-            os.close(fd)
-            try:
-                dst = sqlite3.connect(tmp)
-                try:
-                    self._conn.backup(dst)
-                    dst.commit()
-                finally:
-                    dst.close()
-                with open(tmp, "rb") as f:
-                    fileobj.write(f.read())
-            finally:
-                os.unlink(tmp)
+            # fold the WAL into the main image first, or commits since
+            # the last checkpoint would be missing from the snapshot
+            self._conn.execute("PRAGMA wal_checkpoint(TRUNCATE)")
+            fileobj.write(self._conn.serialize())
 
 
 class _SqliteCursor(Cursor):

@@ -141,8 +141,16 @@ def cmd_start(args) -> int:
         dkg_timeout=args.dkg_timeout,
         use_device_verifier=not args.no_tpu)
     from .core.daemon import DrandDaemon
+    if cfg.use_device_verifier:
+        from . import compile_cache
+        compile_cache.enable()
     daemon = DrandDaemon(cfg)
-    daemon.start()
+    try:
+        daemon.start()
+    except RuntimeError as e:
+        print(f"drand: {e}", file=sys.stderr)
+        daemon.stop()
+        return 1
     if cfg.public_listen:
         from .http_server import RestServer
         daemon.http_server = RestServer(daemon, cfg.public_listen,

@@ -172,19 +172,24 @@ class BeaconProcess:
 
     # -- store / handler plumbing -------------------------------------------
 
-    def _create_store(self):
+    def _create_store(self, require_previous: bool = False):
         """Storage backend switch (drand_beacon.go:340-373):
-        sqlite (bolt-equivalent embedded, default) | memdb | postgres."""
+        sqlite (bolt-equivalent embedded, default) | memdb | postgres.
+        The row stores keep (round, signature) only; for a chained chain
+        they must rebuild previous_sig on read (chain/beacon.go:90-97), or
+        a public_rand of the default scheme goes out without it."""
         if self.cfg.db_engine == "memdb":
             return MemDBStore(self.cfg.memdb_size)
         if self.cfg.db_engine == "postgres":
             from ..chain.postgresdb import PostgresStore
-            return PostgresStore(self.cfg.pg_dsn, self.beacon_id)
+            return PostgresStore(self.cfg.pg_dsn, self.beacon_id,
+                                 require_previous=require_previous)
         if self.cfg.db_engine != "sqlite":
             raise ValueError(f"unknown db engine {self.cfg.db_engine!r}")
         db_dir = self.cfg.db_folder(self.beacon_id)
         os.makedirs(db_dir, mode=0o700, exist_ok=True)
-        return SqliteStore(os.path.join(db_dir, "chain.db"))
+        return SqliteStore(os.path.join(db_dir, "chain.db"),
+                           require_previous=require_previous)
 
     def chain_info(self) -> Optional[Info]:
         if self.group is None or self.group.public_key is None:
@@ -391,7 +396,7 @@ class BeaconProcess:
             if self.handler is not None:
                 return
             assert self.group is not None and self.share is not None
-            self.store = self._create_store()
+            self.store = self._create_store(self.group.scheme.chained)
             # ONE daemon-owned verify pipeline for everything this chain
             # verifies: aggregation-time partials ride the LIVE lane
             # (preempting background work at chunk boundaries), while the

@@ -180,8 +180,14 @@ class Config:
         integrity scans, catch-up sync and client sweeps coalesce into
         the same device batches."""
         if self._verify_service is None:
+            from ..crypto.device_pool import DevicePool
             from ..crypto.verify_service import VerifyService
+            # a host-only daemon (--no-tpu) gets a deviceless pool: its
+            # handles never enumerate devices, so it never initializes a
+            # jax backend (nor takes the chip from the daemon that owns it)
             self._verify_service = VerifyService(
+                pool=None if self.use_device_verifier
+                else DevicePool(devices=[]),
                 clock=self.clock, pad=self.verify_pad,
                 background_window=self.verify_window,
                 watchdog_factor=self.verify_watchdog_factor or None,

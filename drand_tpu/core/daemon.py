@@ -84,6 +84,8 @@ class DrandDaemon:
     # -- lifecycle -----------------------------------------------------------
 
     def start(self) -> None:
+        if self.cfg.use_device_verifier:
+            self._require_devices()
         self._note_start()
         self.gateway.start_all()
         self.control.start()
@@ -92,6 +94,31 @@ class DrandDaemon:
         self.log.info("daemon started",
                       private=self.gateway.listen_addr,
                       control=self.control.port)
+
+    def _require_devices(self) -> None:
+        """Bring the accelerator backend up before serving anything, and
+        require a TPU.  jax registers the TPU backend to fail quietly: a
+        chip that cannot initialize (e.g. another process holds it)
+        leaves jax on the CPU without an error.  So a device verifier
+        that finds anything but a TPU stops the start — unless
+        JAX_PLATFORMS names that platform first, as jax's default (the
+        CPU test suite).  The verify service's failover is for a device
+        lost mid-run, not for serving every round on the host from the
+        first one (`--no-tpu` is the host-only mode)."""
+        from ..crypto.device_pool import jax_devices
+        try:
+            devs = jax_devices()
+        except Exception as e:
+            raise RuntimeError(
+                f"device verifier: backend init failed: {e}") from e
+        named = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
+        got = devs[0].platform if devs else "no jax"
+        if got not in ("tpu", named):
+            raise RuntimeError(
+                f"device verifier: no TPU (jax runs on {got}; another "
+                "process may hold the chip) — start with --no-tpu for "
+                "host-only verification")
+        self.log.info("device verifier", platform=got, devices=len(devs))
 
     def _note_start(self) -> None:
         """Restart observability (fleet harness): bump the persisted

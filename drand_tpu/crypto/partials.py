@@ -25,7 +25,7 @@ Occupancy fast path (ISSUE 10, ported from the r4 G1/G2 verify machinery):
     G2 (32-step joint ladder) and φ-split 2-way on G1 (64-step), exactly
     like crypto/batch.py's verify pipelines, instead of a 128-step
     per-bit ladder.  Soundness is unchanged: coefficients are sampled
-    directly in split form (injective; see batch._rlc_scalars).
+    directly in split form (injective; see batch._device_rlc_bits).
 
 Slot layout: callers pass ragged per-round partial lists (wire format:
 be16(index) || sig); rows are padded to the widest row and masked.
@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import tbls as HT
-from .batch import (_NEG_G1, _NEG_G2, _count_dispatch, _device_rlc_bits,
+from .batch import (_NEG_G1, _NEG_G2, _device_rlc_bits, run_program,
                     _gen_sub, _rlc_keys, _wire_parse, _GEN_JAC_G1,
                     _GEN_JAC_G2, _GEN_SIGN_G1, _GEN_SIGN_G2, _GEN_X_G1,
                     _GEN_X_G2, FRONT_DIGEST, FRONT_FIELDS, _h2f_front,
@@ -352,8 +352,8 @@ class BatchPartialVerifier:
             onehot[i] = (flat_idx == s) & flat_valid
         # per-slot randomizers are sampled on device from a fresh 128-bit
         # key (batch._device_rlc_bits); invalid slots get zero coefficients
-        _count_dispatch()
-        _, all_ok = _rlc_pipeline(self.g2sig, front, self.scheme.dst)(
+        _, all_ok = run_program(
+            _rlc_pipeline(self.g2sig, front, self.scheme.dst),
             sig_x, sign_d, msg, jnp.asarray(_rlc_keys()),
             jnp.asarray(flat_valid.astype(np.uint32)), jnp.asarray(onehot),
             self._pk_sel(signers), self.fixed_aff)
@@ -362,8 +362,7 @@ class BatchPartialVerifier:
 
         # exact fallback: per-slot pairings with per-slot public shares
         pk_slot = self._pk_sel(idxs.reshape(-1))
-        _count_dispatch()
-        got = np.asarray(_exact_pipeline(self.g2sig, front,
-                                         self.scheme.dst)(
+        got = np.asarray(run_program(
+            _exact_pipeline(self.g2sig, front, self.scheme.dst),
             sig_x, sign_d, msg, pk_slot, self.fixed_aff))
         return got.reshape(r, k) & valid

@@ -1,4 +1,4 @@
-"""Scheme registry: the three drand beacon schemes as declarative configs.
+"""Scheme registry: the four drand beacon schemes as declarative configs.
 
 Mirrors the capability surface of the reference's crypto.Scheme
 (crypto/schemes.go:46-204):
@@ -6,11 +6,13 @@ Mirrors the capability surface of the reference's crypto.Scheme
   pedersen-bls-chained    keys G1 (48B), sigs G2 (96B), digest = H(prevSig||round)
   pedersen-bls-unchained  keys G1 (48B), sigs G2 (96B), digest = H(round)
   bls-unchained-on-g1     keys G2 (96B), sigs G1 (48B), digest = H(round)
+  bls-unchained-g1-rfc9380  the same with the RFC 9380 G1 DST (quicknet)
 
 DST note: this era's kyber-bls12381 uses the G2-suite DST string for *both*
 sig groups (the historical short-sig quirk) — pinned here by the mainnet
 known-answer vectors (crypto/schemes_test.go:90-115), which only verify with
 DST "BLS_SIG_BLS12381G2_XMD:SHA-256_SSWU_RO_NUL_" on the G1 scheme too.
+bls-unchained-g1-rfc9380 is the scheme that fixed it (DST_G1).
 
 Host (pure-Python) sign/verify lives here; the batched device path is in
 drand_tpu.crypto.jax (batch_verify / tbls kernels).
@@ -22,7 +24,7 @@ import secrets
 from dataclasses import dataclass
 from typing import Optional
 
-from .host.params import R, DST_G2
+from .host.params import R, DST_G1, DST_G2
 from .host import curve as C
 from .host import h2c as H2C
 from .host import serialize as S
@@ -31,6 +33,7 @@ from .host.pairing import pairing_check
 DEFAULT_SCHEME_ID = "pedersen-bls-chained"
 UNCHAINED_SCHEME_ID = "pedersen-bls-unchained"
 SHORT_SIG_SCHEME_ID = "bls-unchained-on-g1"
+RFC9380_SCHEME_ID = "bls-unchained-g1-rfc9380"
 
 
 def _h2c_g1(msg, dst):
@@ -148,6 +151,8 @@ _SCHEMES = {
     DEFAULT_SCHEME_ID: Scheme(DEFAULT_SCHEME_ID, GroupG2, GroupG1, chained=True),
     UNCHAINED_SCHEME_ID: Scheme(UNCHAINED_SCHEME_ID, GroupG2, GroupG1, chained=False),
     SHORT_SIG_SCHEME_ID: Scheme(SHORT_SIG_SCHEME_ID, GroupG1, GroupG2, chained=False),
+    RFC9380_SCHEME_ID: Scheme(RFC9380_SCHEME_ID, GroupG1, GroupG2,
+                              chained=False, dst=DST_G1),
 }
 
 

@@ -2,7 +2,7 @@
 
 Sequential scan stages (the 758-step E2 pow, the ladders) cost per STEP,
 not per lane, so wider pads amortize them — but the best (pad, depth)
-point depends on the accelerator: on a real chip the ~74 ms dispatch RPC
+point depends on the accelerator: on a chip the per-dispatch latency
 favours wide pads and deep pipelines, on the CPU test backend compile
 time dominates and today's 8192x1 is right.  `tools/autotune.py` sweeps
 pad x depth per (scheme kind, backend platform) and persists the winner

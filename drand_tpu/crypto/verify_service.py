@@ -79,6 +79,7 @@ import os
 import threading
 
 from ..common import make_condition, make_lock
+from .device_pool import thread_compiling
 from collections import deque
 from concurrent.futures import Future
 from typing import Callable, Dict, List, Optional, Tuple
@@ -104,9 +105,13 @@ DEFAULT_LIVE_WINDOW = 0.0   # live work flushes immediately
 # override per daemon; the env vars override the module defaults the same way
 # net/resilience.py's DRAND_RETRY_* family does).  The deadline for a device
 # dispatch is max(FLOOR, FACTOR * observed p99 of this service's own dispatch
-# latencies): the factor keeps a healthy-but-slow chip off the trip wire, the
-# floor covers cold XLA compiles, which are minutes-scale and look exactly
-# like a hang to anything less patient.
+# latencies): the factor keeps a healthy-but-slow chip off the trip wire,
+# the floor bounds a cold slot with no latency history.  Compilation is
+# minutes-scale on the chip's host and is not counted: the watchdog
+# re-arms a deadline while the dispatching thread is still compiling
+# (`device_pool.compiling`, marked by batch.run_program), up to
+# DEFAULT_COMPILE_LIMIT seconds from the dispatch's start — past it a
+# compile is taken for a hang like any other stall.
 # Huge-batch round-axis sharding (ISSUE 11): a single submission of at
 # least this many rounds routes to the pool-wide sharded backend instead
 # of its handle's device group.  0 = AUTO: pad x max(2, pool devices) —
@@ -119,6 +124,7 @@ DEFAULT_WATCHDOG_FACTOR = float(
     os.environ.get("DRAND_VERIFY_WATCHDOG_FACTOR", "8"))
 DEFAULT_WATCHDOG_FLOOR = float(
     os.environ.get("DRAND_VERIFY_WATCHDOG_FLOOR", "120"))
+DEFAULT_COMPILE_LIMIT = 1800.0     # seconds a compiling dispatch is spared
 DEFAULT_PROBE_INTERVAL = float(
     os.environ.get("DRAND_VERIFY_PROBE_INTERVAL", "5"))
 
@@ -235,10 +241,10 @@ class _Ticket:
     not hung."""
 
     __slots__ = ("slot", "batch", "kind", "started", "deadline_at",
-                 "budget", "cancelled")
+                 "budget", "cancelled", "thread")
 
     def __init__(self, slot, batch, kind, started, deadline_at,
-                 budget=None):
+                 budget=None, thread=None):
         self.slot = slot
         self.batch = batch
         self.kind = kind            # "chunk" | "call" | "probe"
@@ -247,6 +253,7 @@ class _Ticket:
         self.budget = budget if budget is not None \
             else max(0.0, deadline_at - started)
         self.cancelled = False
+        self.thread = thread        # ident of the thread running the call
 
 
 class _BackendSlot:
@@ -469,6 +476,7 @@ class VerifyService:
         self._watchdog_trips = 0
         self._migrations = 0        # group→sibling backend rebuilds
         self._sharded_dispatches = 0    # pool-wide huge-batch dispatches
+        self._calls_ok = {ln: 0 for ln in LANES}    # opaque calls served
         self._concurrent_max = 0    # most streams mid-dispatch at once
 
     # -- handles / backends --------------------------------------------------
@@ -498,7 +506,7 @@ class VerifyService:
         pk = bytes(public_key_bytes)
         if backend is not None or backend_factory is not None:
             kind = "custom"
-        elif device and self._device_available():
+        elif device and self._get_pool().n_devices:
             kind = "device"
         else:
             kind = "host"
@@ -707,21 +715,18 @@ class VerifyService:
                 self, inner_factory(scheme, pub_poly, n_nodes), fb)
         return factory
 
-    @staticmethod
-    def _device_available() -> bool:
-        try:
-            import jax  # noqa: F401
-            return True
-        except Exception:
-            return False
-
-    @staticmethod
-    def _platform() -> str:
-        try:
-            import jax
-            return jax.default_backend()
-        except Exception:
-            return "cpu"
+    def _slot_platform(self, slot: _BackendSlot) -> str:
+        """What a handle's work runs on right now: its group's device
+        platform ("tpu", "cpu", ...) while the device backend serves,
+        "host" for host handles and failed-over device handles, "custom"
+        for injected backends."""
+        if slot.kind == "custom":
+            return "custom"
+        if slot.kind != "device" or slot.active() is not slot.primary:
+            return "host"
+        pool = self._pool
+        return pool.group(slot.gid).platform if pool is not None \
+            else "host"
 
     def _tuned(self, scheme, group_size: int = 1):
         """(pad, depth) for a new handle: explicit ctor overrides pin;
@@ -739,7 +744,7 @@ class VerifyService:
         consult = tuning.tuning_path() is not None \
             or os.environ.get("DRAND_VERIFY_PAD") \
             or os.environ.get("DRAND_VERIFY_PIPELINE_DEPTH")
-        platform = self._platform() if consult else "cpu"
+        platform = self._get_pool().platform if consult else "cpu"
         pad, depth, _src = tuning.resolve(
             kind, platform, pad=self.pad_override or None,
             depth=self.depth_override or None, group_size=group_size)
@@ -1134,6 +1139,8 @@ class VerifyService:
                               self.clock.monotonic() - t0, gid=batch.gid)
                 return
         req.future.set_result(out)
+        with self._cond:
+            self._calls_ok[batch.lane] += 1
         self._account(batch.lane, 1, 1, self.clock.monotonic() - t0,
                       gid=batch.gid)
 
@@ -1191,7 +1198,7 @@ class VerifyService:
         """Device path: host packing of chunk k+1 overlaps device compute
         of chunk k, generalized to a DEPTH-K in-flight window (ISSUE 10):
         up to `depth` dispatches stay enqueued ahead of the resolve point
-        so the per-dispatch RPC latency amortizes across the window
+        so the per-dispatch latency amortizes across the window
         instead of being paid serially per chunk.  Preemption checks stay
         at chunk boundaries; per-chunk errors stay contained.  The
         watchdog deadline of each resolve is scaled by the number of
@@ -1322,11 +1329,13 @@ class VerifyService:
     def _deadline_for(self, slot: Optional[_BackendSlot],
                       scale: int = 1) -> float:
         """Watchdog deadline: a generous multiple of this slot's observed
-        p99 dispatch latency, floored for cold compiles; opaque calls
-        (no slot) get the floor.  `scale` is the number of in-flight
-        dispatches sharing the device under depth-k pipelining: the
-        deadline budget covers the whole window on its OLDEST ticket
-        (scaling the p99 term, never the cold-compile floor)."""
+        p99 dispatch latency, with a floor; opaque calls (no slot) get
+        the floor.  Time spent compiling never counts against it (the
+        watchdog re-arms while the dispatching thread compiles).
+        `scale` is the number of in-flight dispatches sharing the device
+        under depth-k pipelining: the deadline budget covers the whole
+        window on its OLDEST ticket (scaling the p99 term, never the
+        floor)."""
         with self._cond:
             lat = sorted(slot.latencies) if slot is not None else []
         if lat:
@@ -1344,7 +1353,8 @@ class VerifyService:
         deadline = self._deadline_for(slot, scale)
         with self._cond:
             started = self.clock.monotonic()
-            ticket = _Ticket(slot, batch, kind, started, started + deadline)
+            ticket = _Ticket(slot, batch, kind, started, started + deadline,
+                             thread=threading.get_ident())
             self._tickets[id(ticket)] = ticket
             self._cond.notify_all()     # the watchdog re-arms on new work
         err = None
@@ -1598,6 +1608,12 @@ class VerifyService:
                     if t.slot is not None and oldest.get(id(t.slot)) is not t:
                         continue
                     if not t.cancelled and now >= t.deadline_at:
+                        if now - t.started < DEFAULT_COMPILE_LIMIT \
+                                and thread_compiling(t.thread):
+                            # still compiling: not hung — judge the
+                            # dispatch from when its program is ready
+                            t.deadline_at = now + t.budget
+                            continue
                         t.cancelled = True
                         del self._tickets[tid]
                         tripped.append(t)
@@ -1971,12 +1987,19 @@ class VerifyService:
             return {
                 "submitted": self._submitted,
                 "dispatches": self._dispatches,
+                # opaque calls served per lane (live = aggregation-time
+                # partial verification)
+                "calls": dict(self._calls_ok),
                 "preemptions": self._preemptions,
                 "failovers": self._failovers,
                 "promotions": self._promotions,
                 "watchdog_trips": self._watchdog_trips,
                 "backends": {s.label: s.state
                              for s in self._slots.values()},
+                # where each handle's work runs: a "device" handle on a
+                # host with no chip says "cpu" here, not "tpu"
+                "platforms": {s.label: self._slot_platform(s)
+                              for s in self._slots.values()},
                 "fill_ratio": (self._dispatch_lanes /
                                self._dispatch_slots
                                if self._dispatch_slots else 0.0),

@@ -22,16 +22,21 @@ Reference analogue: this file plays the role of the x86-64 assembly in
 `kilic/bls12-381` (SURVEY.md §2.9) — the hand-scheduled native backend under
 a generic field interface.
 
-Engine selection (`DRAND_TPU_PALLAS`): `auto` (default) — dispatch on only
-when the default backend is TPU; `1`/`interp` — dispatch on everywhere;
-`0` — off.  The Mosaic-compiled kernels run only on TPU; on other backends
-the dispatch runs the IDENTICAL chain math (`_pow_math`/`_ladder_*_math`)
-as plain jitted XLA — that is what the CPU test suite covers, plus the
-operand/layout wrappers shared by both lowerings.
+Engine selection (`DRAND_TPU_PALLAS`, read by `enabled()`): it chooses the
+LANE-MAJOR engine of this module over the XLA limb engine — `auto`
+(default) on a TPU backend only, `1`/`interp` on every backend (the two
+spellings are the same setting), `0` never.  It does not choose how the
+lane-major chains lower: that is `_use_kernels()`, true exactly on a TPU
+backend, where each chain runs as its compiled Pallas kernel.  Elsewhere
+the IDENTICAL chain math (`_pow_math`/`_ladder_*_math`) runs as plain
+jitted XLA — that is what the CPU test suite covers, plus the
+operand/layout wrappers shared by both lowerings.  No setting runs the
+Pallas interpreter.  `engine_mode()` names the combination in force.
 """
 
 import math
 import os
+import threading
 from contextlib import contextmanager
 from functools import lru_cache
 
@@ -63,7 +68,17 @@ TILE = int(os.environ.get("DRAND_TPU_PALLAS_TILE", "256"))
 # each kernel as operands (a stacked (K, 24, tile) bundle for the pairing
 # kernels; (24, TILE) p/one pair for the chain kernels), installed for the
 # trace via this context.  Outside any kernel the numpy fallbacks apply.
-_CTX = {}
+# Per THREAD: the verify service, several daemons in one process and
+# chip_smoke's warm-up pool trace kernels concurrently, and a context one
+# thread installs must never leak into (or vanish from) another's trace.
+_TLS = threading.local()
+
+
+def _ctx() -> dict:
+    ctx = getattr(_TLS, "ctx", None)
+    if ctx is None:
+        ctx = _TLS.ctx = {}
+    return ctx
 
 
 def _mont_np(x: int) -> np.ndarray:
@@ -93,10 +108,11 @@ NCONST = len(_CONST_ENTRIES)
 
 def _c(name: str):
     """Named field constant in the active layout/context."""
-    if "consts" in _CTX:
-        return _CTX["consts"][_CONST_IDX[name]]
-    if name in _CTX:
-        return _CTX[name]
+    ctx = _ctx()
+    if "consts" in ctx:
+        return ctx["consts"][_CONST_IDX[name]]
+    if name in ctx:
+        return ctx[name]
     return _CONST_STACK[_CONST_IDX[name]][:, None]            # numpy (24, 1)
 
 
@@ -110,13 +126,14 @@ def _one_lane():
 
 @contextmanager
 def _kernel_consts(**kw):
-    old = dict(_CTX)
-    _CTX.update(kw)
+    ctx = _ctx()
+    old = dict(ctx)
+    ctx.update(kw)
     try:
         yield
     finally:
-        _CTX.clear()
-        _CTX.update(old)
+        ctx.clear()
+        ctx.update(old)
 
 
 _P_FULL = np.ascontiguousarray(np.broadcast_to(_P_LANE, (NL, TILE)))
@@ -130,6 +147,8 @@ def _const_bundle(tile: int) -> np.ndarray:
 
 
 def enabled() -> bool:
+    """True when the lane-major engine replaces the XLA limb engine (see
+    the module docstring: `1`/`interp` do not mean the interpreter)."""
     mode = os.environ.get("DRAND_TPU_PALLAS", "auto")
     if mode == "0":
         return False
@@ -510,6 +529,15 @@ def _use_kernels() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def engine_mode() -> str:
+    """Which engine the field chains run on in this process:
+    "pallas-kernels" (compiled Mosaic kernels, TPU only), "lane-major-xla"
+    (the same chain math as jitted XLA) or "xla-limbs"."""
+    if not enabled():
+        return "xla-limbs"
+    return "pallas-kernels" if _use_kernels() else "lane-major-xla"
+
+
 # ---------------------------------------------------------------------------
 # Compiled Pallas kernels (TPU)
 # ---------------------------------------------------------------------------
@@ -793,7 +821,7 @@ def pf2_inv(a):
     the exponent p-2 enters kernels as a scalar-prefetch bit array)."""
     t = pf_mul_many([(a[0], a[0]), (a[1], a[1])])
     norm = pf_add(t[0], t[1])
-    ninv = _pow_math(_CTX["invbit"], norm, INV_NBITS)
+    ninv = _pow_math(_ctx()["invbit"], norm, INV_NBITS)
     r = pf_mul_many([(a[0], ninv), (a[1], ninv)])
     return (r[0], pf_neg(r[1]))
 
@@ -1012,27 +1040,82 @@ def _miller_math(getbit, px, py, q2, nbits: int):
     return pf12_conj(f)
 
 
-def _finalexp_math(getxbit, f):
-    # easy part: f^((p^6-1)(p^2+1))
-    f = pf12_mul(pf12_conj(f), pf12_inv(f))
-    f = pf12_mul(pf12_frobenius(f, 2), f)
+# The final exponentiation runs as a straight-line program over a few
+# Fp12 registers, interpreted by ONE loop whose body holds a single
+# squaring and a single multiplication site.  Unrolled (5 |x|-power
+# loops + ~10 products, each its own copy of the Fp12 formulas) the
+# kernel took 343 s to compile for v5e (AOT, PR 21); one body site per
+# operation is what the compile time scales with.  Each step reads an
+# (op, operand) code from SMEM; `lax.switch` runs only the taken branch.
+_FE_SQR, _FE_MUL, _FE_SET, _FE_SAVE_G, _FE_SAVE_F, _FE_SAVE_E2 = range(6)
+# operands: 0 G, 1 conj G, 2 frob1 G, 3 frob2 G, 4 F, 5 conj F, 6 inv,
+# 7 conj A, 8 frob2 E2, 9 conj E2
+_FE_NARG = 10
 
-    def pow_x(g):
-        # g^x for x < 0 (cyclotomic: inverse == conjugate); |x| has hw 6,
-        # zero bits skip their multiply via the scalar cond
-        def step(i, acc):
-            acc = pf12_sqr(acc)
-            return _maybe_cond(getxbit(i), lambda a: pf12_mul(a, g), acc)
 
-        return pf12_conj(jax.lax.fori_loop(0, _XLOOP_NBITS, step, g))
+def _fe_program() -> np.ndarray:
+    """The final exponentiation f^((p^12-1)/r) as (op << 4 | operand)
+    codes — the same addition chain as ops/pairing.py:
+      easy part   f <- conj(f)·f^-1, then f <- frob2(f)·f
+      pow_x(g)    = conj(g^|x|)  (x < 0; cyclotomic inverse = conj)
+      e1 = pow_x(f)·conj(f);  e1 = pow_x(e1)·conj(e1)
+      e2 = pow_x(e1)·frob1(e1)
+      e3 = pow_x(pow_x(e2))·frob2(e2)·conj(e2);  result e3·f^3."""
+    code = []
+    op = lambda o, a=0: code.append(o << 4 | a)  # noqa: E731
+    op(_FE_SET, 5)
+    op(_FE_MUL, 6)                      # conj(f) * f^-1
+    op(_FE_SAVE_G)
+    op(_FE_SET, 3)
+    op(_FE_MUL, 0)                      # frob2(f) * f
+    op(_FE_SAVE_F)
+    op(_FE_SAVE_G)
+    posts = [[5], [1], [2], [], [8, 9, 4, 4, 4]]
+    for stage, post in enumerate(posts):
+        op(_FE_SET, 0)                  # A = g
+        for bit in _XLOOP_BITS_NP:      # A = g^|x|
+            op(_FE_SQR)
+            if bit:
+                op(_FE_MUL, 0)
+        op(_FE_SET, 7)                  # A = pow_x(g)
+        for a in post:
+            op(_FE_MUL, a)
+        if stage == 2:
+            op(_FE_SAVE_E2)             # E2 = e2 (stage 3's input)
+        op(_FE_SAVE_G)
+    return np.asarray(code, np.int32)
 
-    e1 = pf12_mul(pow_x(f), pf12_conj(f))
-    e1 = pf12_mul(pow_x(e1), pf12_conj(e1))
-    e2 = pf12_mul(pow_x(e1), pf12_frobenius(e1, 1))
-    e3 = pf12_mul(pf12_mul(pow_x(pow_x(e2)), pf12_frobenius(e2, 2)),
-                  pf12_conj(e2))
-    f3 = pf12_mul(pf12_sqr(f), f)
-    return pf12_mul(e3, f3)
+
+def _finalexp_math(getcode, f, ncode: int):
+    inv = pf12_inv(f)
+    operands = [
+        lambda r: r[1], lambda r: pf12_conj(r[1]),
+        lambda r: pf12_frobenius(r[1], 1), lambda r: pf12_frobenius(r[1], 2),
+        lambda r: r[2], lambda r: pf12_conj(r[2]), lambda r: r[4],
+        lambda r: pf12_conj(r[0]), lambda r: pf12_frobenius(r[3], 2),
+        lambda r: pf12_conj(r[3])]
+    assert len(operands) == _FE_NARG
+
+    def step(i, regs):                  # regs = (A, G, F, E2, inv)
+        code = getcode(i)
+        x = jax.lax.switch(code & 15, operands, regs)
+        a, g, f_, e2, iv = regs
+        return jax.lax.switch(code >> 4, [
+            lambda: (pf12_sqr(a), g, f_, e2, iv),
+            lambda: (pf12_mul(a, x), g, f_, e2, iv),
+            lambda: (x, g, f_, e2, iv),
+            lambda: (a, a, f_, e2, iv),
+            lambda: (a, g, a, e2, iv),
+            lambda: (a, g, f_, a, iv)])
+
+    # every register is written before it is read (the program opens
+    # with a SET); seeding them from data keeps one lane layout in the
+    # loop carry — Mosaic refuses a carry seeded from broadcast constants
+    regs = jax.lax.fori_loop(0, ncode, step, (f, f, f, f, inv))
+    return regs[0]
+
+
+_FE_CODE_NP = _fe_program()
 
 
 def _flat12(f):
@@ -1091,12 +1174,12 @@ def _miller_direct():
 
 @lru_cache(maxsize=None)
 def _finalexp_call(btot: int):
-    def kernel(xbits_ref, invbits_ref, consts_ref, *refs):
+    def kernel(code_ref, invbits_ref, consts_ref, *refs):
         with _kernel_consts(consts=consts_ref[:, :, 0:1],
                             invbit=lambda i: invbits_ref[i]):
             ins, outs = refs[:12], refs[12:]
             f = _pack12([r[:] for r in ins])
-            out = _finalexp_math(lambda i: xbits_ref[i], f)
+            out = _finalexp_math(lambda i: code_ref[i], f, len(_FE_CODE_NP))
             for o, v in zip(outs, _flat12(out)):
                 o[:] = v
 
@@ -1117,10 +1200,11 @@ def _finalexp_call(btot: int):
 @lru_cache(maxsize=None)
 def _finalexp_direct():
     @jax.jit
-    def run(xbits, invbits, *arrs):
+    def run(code, invbits, *arrs):
         with _kernel_consts(invbit=lambda i: invbits[i]):
             f = _pack12(list(arrs))
-            return tuple(_flat12(_finalexp_math(lambda i: xbits[i], f)))
+            return tuple(_flat12(_finalexp_math(lambda i: code[i], f,
+                                                len(_FE_CODE_NP))))
 
     return run
 
@@ -1148,13 +1232,13 @@ def final_exponentiation(f):
     b = int(np.prod(shape)) if shape else 1
     lanes = [_to_lanes(x, PAIR_TILE)[0] for x in flat_in]
     btot = lanes[0].shape[1]
-    xbits = jnp.asarray(_XLOOP_BITS_NP)
+    code = jnp.asarray(_FE_CODE_NP)
     invbits = jnp.asarray(_inv_bits())
     if _use_kernels():
-        out = _finalexp_call(btot)(xbits, invbits,
+        out = _finalexp_call(btot)(code, invbits,
                                    _const_bundle(PAIR_TILE), *lanes)
     else:
-        out = _finalexp_direct()(xbits, invbits, *lanes)
+        out = _finalexp_direct()(code, invbits, *lanes)
     leaves = [_from_lanes(x, shape, b) for x in out]
     return _pack12(leaves)
 
@@ -1167,20 +1251,27 @@ def final_exponentiation(f):
 # ---------------------------------------------------------------------------
 
 
-def _sum_tile_math(kind: str, pt):
+def _sum_tile_math(kind: str, pt, roll=None):
     """Reduce a (…, 24, W) point across lanes: log2(W) rotate-and-add levels
     at CONSTANT width (Mosaic rejects the narrowing layouts a halving tree
-    produces below 128 lanes).  Lane 0 holds the sum afterwards; the other
-    lanes carry partial garbage.  W must be a power of two."""
+    produces below 128 lanes).  The levels are ONE loop body with a
+    dynamic rotate (W/2, W/4, …, 1 lanes): an unrolled level chain
+    compiled for minutes per kernel (v5e AOT: 81 s for G1, 324 s for G2
+    at 8 levels).  Cyclic rotate-and-add leaves the total in every lane,
+    whichever way the rotate turns; callers read lane 0.  `roll(t, sh)`
+    is the lane rotate (pltpu.roll inside the kernel; jnp.roll in XLA).
+    W must be a power of two."""
     curve = _curve_of(kind)
     w = _flat_point(pt)[0].shape[-1]
     assert w & (w - 1) == 0, "rotate-and-add reduction needs power-of-two width"
-    sh = w // 2
-    while sh >= 1:
-        rolled = jax.tree.map(lambda t: jnp.roll(t, -sh, axis=-1), pt)
-        pt = curve.add(pt, rolled)
-        sh //= 2
-    return pt
+    if roll is None:
+        roll = lambda t, sh: jnp.roll(t, sh, axis=-1)  # noqa: E731
+
+    def level(i, acc):
+        sh = jnp.right_shift(jnp.int32(w // 2), i)
+        return curve.add(acc, jax.tree.map(lambda t: roll(t, sh), acc))
+
+    return jax.lax.fori_loop(0, w.bit_length() - 1, level, pt)
 
 
 @lru_cache(maxsize=None)
@@ -1191,7 +1282,9 @@ def _sum_call(kind: str, btot: int):
         with _kernel_consts(p=p_ref[:, 0:1], one=one_ref[:, 0:1]):
             ins, outs = refs[:nc], refs[nc:]
             pt = _pack_point(kind, [r[:] for r in ins])
-            acc = _sum_tile_math(kind, pt)
+            acc = _sum_tile_math(
+                kind, pt,
+                roll=lambda t, sh: pltpu.roll(t, sh, axis=t.ndim - 1))
             # a (24, 1) output tile violates Mosaic's lane-tiling minimum —
             # broadcast lane 0 (the sum) across the tile; the caller reads
             # lane 0 of each tile (strided slice in XLA)

@@ -229,12 +229,11 @@ def test_recover_batch_is_one_dispatch():
     assert out == [host]
 
 
-def test_dispatch_packed_retry_after_donation():
+def test_dispatch_packed_retry():
     """Review regression (PR 9): the verify service's failover ladder
     re-invokes dispatch_packed ONCE after a transient fault — the retry
-    must rebuild the donated encoding from the retained host arrays, not
-    crash on the consumed buffer (which would turn every transient fault
-    into a premature host failover)."""
+    must find the encoding intact (which would otherwise turn every
+    transient fault into a premature host failover)."""
     sch, sec, ver8 = _keyed_verifier("bls-unchained-on-g1")
     pub = sch.public_bytes(sch.keypair(seed=b"batch-test")[1])
     ver = batch.BatchBeaconVerifier(sch, pub, pad_to=8)
@@ -244,12 +243,12 @@ def test_dispatch_packed_retry_after_donation():
     orig = ver._rlc_dispatch
     calls = {"n": 0}
 
-    def flaky(enc, n, donate=False, front=None):
+    def flaky(enc, n, front=None):
         calls["n"] += 1
         assert enc is not None, "retry saw a consumed encoding"
         if calls["n"] == 1:
             raise ConnectionError("transient dispatch fault")
-        return orig(enc, n, donate=donate, front=front)
+        return orig(enc, n, front=front)
 
     ver._rlc_dispatch = flaky
     with pytest.raises(ConnectionError):
@@ -388,7 +387,7 @@ def test_device_h2f_stream_entry_is_one_dispatch():
     sigs = [b.signature for b in beacons]
     pub = sch.public_bytes(sch.keypair(seed=b"batch-test")[1])
     ver = batch.BatchBeaconVerifier(sch, pub, h2f_device=True)
-    # warm the donating raw program so the counted pass measures steady
+    # warm the raw program so the counted pass measures steady
     # state (a cold pass takes the same count; this keeps timing honest)
     packed = ver.pack_chunk(rounds, sigs)
     assert ver.resolve_packed(packed, ver.dispatch_packed(packed)).all()

@@ -10,6 +10,8 @@ fan-out verdicts against the real `HostBatchVerifier`."""
 import threading
 import types
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -576,6 +578,116 @@ def test_watchdog_abandons_hung_dispatch_and_fails_over():
     assert st["watchdog_trips"] == 2
     assert st["failovers"] == 1
     dev.release.set()               # free the abandoned dispatch threads
+    svc.stop()
+
+
+def _blocking_program(release):
+    """A jitted program whose trace — inside its first call — waits on
+    `release`: a stand-in for a multi-minute cold compile."""
+    def f(x):
+        release.wait(30)
+        return x + 1
+    return jax.jit(f)
+
+
+def test_run_program_marks_only_a_first_call_compiling():
+    from drand_tpu.crypto.batch import run_program
+    from drand_tpu.crypto.device_pool import thread_compiling
+    ev = threading.Event()
+    prog = _blocking_program(ev)
+    th = threading.Thread(target=run_program, args=(prog, jnp.ones(4)))
+    th.start()
+    try:
+        deadline = time.monotonic() + 10
+        while not thread_compiling(th.ident) \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert thread_compiling(th.ident)
+        assert not thread_compiling(threading.get_ident())
+    finally:
+        ev.set()
+        th.join()
+    assert not thread_compiling(th.ident)
+    ev.clear()              # a second trace would now block for 30 s
+    t0 = time.monotonic()
+    assert run_program(prog, jnp.ones(4)).tolist() == [2.0] * 4
+    assert time.monotonic() - t0 < 10
+
+
+class CompilingBackend(StubBackend):
+    """verify_batch first runs a program whose compile waits on
+    `release`."""
+
+    def __init__(self):
+        super().__init__()
+        self.release = threading.Event()
+        self.prog = _blocking_program(self.release)
+
+    def verify_batch(self, rounds, sigs, prev_sigs=None):
+        from drand_tpu.crypto.batch import run_program
+        self.started.set()
+        run_program(self.prog, jnp.ones(len(rounds)))
+        return super().verify_batch(rounds, sigs, prev_sigs)
+
+
+def test_watchdog_spares_a_compiling_dispatch():
+    """A cold compile is minutes of host work on the chip's machine: a
+    dispatch still compiling past its deadline is not hung — no trip, no
+    failover, the device answers once its program is ready."""
+    svc = make_service(pad=8, watchdog_floor=10.0)
+    dev, fb = CompilingBackend(), StubBackend()
+    h = svc.handle(SCHEME, PK, backend=dev, fallback=fb)
+    f = h.submit(*beacons([1, 2]), lane=LANE_LIVE)
+    assert dev.started.wait(10)
+    for _ in range(3):
+        svc.clock.advance(11.0)
+        time.sleep(0.2)             # several watchdog polls
+    assert svc.stats()["watchdog_trips"] == 0
+    dev.release.set()
+    assert f.result(10).tolist() == [True, True]
+    st = svc.stats()
+    assert fb.calls == [] and st["failovers"] == 0
+    assert st["backends"] == {svc._slots[h.key].label: "healthy"}
+    svc.stop()
+
+
+def test_watchdog_trips_a_compile_past_the_compile_limit(monkeypatch):
+    """A compile that never ends is a hang: past COMPILE_LIMIT from the
+    dispatch's start the watchdog trips it like any other stall."""
+    from drand_tpu.crypto import verify_service as VS
+    monkeypatch.setattr(VS, "DEFAULT_COMPILE_LIMIT", 25.0)
+    svc = make_service(pad=8, watchdog_floor=10.0)
+    dev, fb = CompilingBackend(), StubBackend()
+    h = svc.handle(SCHEME, PK, backend=dev, fallback=fb)
+    f = h.submit(*beacons([1, 2]), lane=LANE_LIVE)
+    assert dev.started.wait(10)
+    try:
+        for _ in range(6):
+            svc.clock.advance(11.0)
+            time.sleep(0.2)
+        assert svc.stats()["watchdog_trips"] >= 1
+    finally:
+        dev.release.set()
+    assert f.result(10).tolist() == [True, True]
+    svc.stop()
+
+
+def test_stats_name_the_platform_each_handle_runs_on():
+    """A "device" handle on a host with no chip runs on the CPU — the
+    stats say so, and injected/host handles say what they are."""
+    from drand_tpu.crypto import schemes
+    svc = make_service()
+    sch = schemes.scheme_from_name(schemes.SHORT_SIG_SCHEME_ID)
+    pubs = [sch.public_bytes(sch.keypair(seed=s)[1])
+            for s in (b"platform-dev", b"platform-host")]
+    dev = svc.handle(sch, pubs[0], device=True)
+    host = svc.handle(sch, pubs[1], device=False)
+    custom = svc.handle(SCHEME, PK, backend=StubBackend())
+    plats = svc.stats()["platforms"]
+    label = lambda h: svc._slots[h.key].label  # noqa: E731
+    assert plats[label(dev)] == jax.devices()[0].platform == "cpu"
+    assert plats[label(host)] == "host"
+    assert plats[label(custom)] == "custom"
     svc.stop()
 
 
