@@ -35,7 +35,7 @@ per-function lockset summaries (`FunctionSummary.acquires_trans`,
      acquiring the registry's lock while this class's lock is held is an
      edge, as is a callback registered with another class and invoked
      under that class's lock (the tenancy ``on_change`` →
-     admission/placement shape).  Module-level locks (`_PACK_LOCK =
+     admission/placement shape).  Module-level locks (`_HOST_H2F_LOCK =
      threading.Lock()`) are graph nodes too.
 
   5. **helper-laundered write** (``lock-helper-mutation``) — passing a

@@ -145,7 +145,7 @@ class ModuleInfo:
                 for t in node.targets:
                     if isinstance(t, ast.Name):
                         self.module_defs.add(t.id)
-                # module-level locks (`_PACK_LOCK = threading.Lock()`) are
+                # module-level locks (`_HOST_H2F_LOCK = threading.Lock()`) are
                 # lockset members for the interprocedural lock analysis
                 if isinstance(node.value, ast.Call):
                     ctor = self.resolve(dotted(node.value.func) or "")

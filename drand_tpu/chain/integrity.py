@@ -202,7 +202,8 @@ class IntegrityScanner:
         silently falls back to a full walk (`report.resumed_from` says
         which happened).  Every scan emits a fresh `report.checkpoint`
         advancing the watermark over the rounds that scanned clean."""
-        from ..metrics import integrity_beacons_scanned, integrity_corrupt_found
+        from ..metrics import (integrity_beacons_scanned,
+                               integrity_corrupt_found, span)
         if mode not in (MODE_LINKAGE, MODE_FULL):
             raise ValueError(f"unknown scan mode {mode!r}")
         if mode == MODE_FULL and self.verifier is None:
@@ -266,56 +267,70 @@ class IntegrityScanner:
             if progress is not None:
                 progress(done_round, report.upto)
 
+        # `integrity.read`: the rows between two flushes (cursor reads,
+        # linkage, digest), one span per chunk; the flush's verify is
+        # the verify service's own spans
         cur = self.store.cursor()
         b = _cursor_seek(cur, start_round)
         while b is not None and b.round <= report.upto:
-            r = b.round
-            if r > prev_round + 1:
-                for gap in range(prev_round + 1, r):
-                    report.findings.append(Finding(gap, MISSING))
-                # the walk anchor is lost across a hole; fall back to the
-                # store's own previous_sig below when it has one
-                prev_sig = None
-            report.scanned += 1
-            unflushed += 1
-            sig = b.signature
-            well_formed = len(sig) == sig_len
-            if not well_formed:
-                # torn write: the row exists but is not a point encoding
-                unverified.add(r)
-                report.findings.append(Finding(
-                    r, MALFORMED,
-                    f"signature is {len(sig)} bytes, want {sig_len}"))
-            elif self.scheme.chained:
-                if b.previous_sig is not None and prev_sig is not None \
-                        and r == prev_round + 1 and b.previous_sig != prev_sig:
-                    report.findings.append(Finding(
-                        r, UNLINKED,
-                        "stored previous_sig does not match round "
-                        f"{r - 1}'s stored signature"))
-                use_prev = prev_sig if prev_sig is not None else b.previous_sig
-                if use_prev is None:
-                    # hole below on a trimmed store: the digest cannot be
-                    # rebuilt, so the round cannot be proven valid — flag
-                    # it for re-fetch rather than vouch for it blindly
-                    unverified.add(r)
-                    report.findings.append(Finding(
-                        r, UNLINKED,
-                        "previous signature unavailable (hole below)"))
-                else:
-                    buf.append(b)
-                    buf_prevs.append(use_prev)
-            else:
-                buf.append(b)
-                buf_prevs.append(None)
-            # a torn row can't anchor the next round's linkage
-            prev_sig = sig if well_formed else None
-            prev_round = r
-            if well_formed:
-                digest = _roll_digest(digest, r, sig)
+            with span("integrity.read", round=b.round):
+                while b is not None and b.round <= report.upto:
+                    r = b.round
+                    if r > prev_round + 1:
+                        for gap in range(prev_round + 1, r):
+                            report.findings.append(Finding(gap, MISSING))
+                        # the walk anchor is lost across a hole; fall back
+                        # to the store's own previous_sig below when it
+                        # has one
+                        prev_sig = None
+                    report.scanned += 1
+                    unflushed += 1
+                    sig = b.signature
+                    well_formed = len(sig) == sig_len
+                    if not well_formed:
+                        # torn write: the row exists but is not a point
+                        # encoding
+                        unverified.add(r)
+                        report.findings.append(Finding(
+                            r, MALFORMED,
+                            f"signature is {len(sig)} bytes, want {sig_len}"))
+                    elif self.scheme.chained:
+                        if b.previous_sig is not None \
+                                and prev_sig is not None \
+                                and r == prev_round + 1 \
+                                and b.previous_sig != prev_sig:
+                            report.findings.append(Finding(
+                                r, UNLINKED,
+                                "stored previous_sig does not match round "
+                                f"{r - 1}'s stored signature"))
+                        use_prev = prev_sig if prev_sig is not None \
+                            else b.previous_sig
+                        if use_prev is None:
+                            # hole below on a trimmed store: the digest
+                            # cannot be rebuilt, so the round cannot be
+                            # proven valid — flag it for re-fetch rather
+                            # than vouch for it blindly
+                            unverified.add(r)
+                            report.findings.append(Finding(
+                                r, UNLINKED,
+                                "previous signature unavailable (hole below)"))
+                        else:
+                            buf.append(b)
+                            buf_prevs.append(use_prev)
+                    else:
+                        buf.append(b)
+                        buf_prevs.append(None)
+                    # a torn row can't anchor the next round's linkage
+                    prev_sig = sig if well_formed else None
+                    prev_round = r
+                    if well_formed:
+                        digest = _roll_digest(digest, r, sig)
+                    if len(buf) >= self.chunk:
+                        break
+                    b = cur.next()
             if len(buf) >= self.chunk:
-                flush(r)
-            b = cur.next()
+                flush(prev_round)
+                b = cur.next()
         for gap in range(prev_round + 1, report.upto + 1):
             report.findings.append(Finding(gap, MISSING))
         flush(report.upto)

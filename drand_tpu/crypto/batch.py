@@ -27,13 +27,13 @@ import os
 import secrets
 import threading
 
-from ..common import make_lock
-import time
 from functools import lru_cache
 
 import jax
 import numpy as np
 
+from .. import metrics
+from ..log import Logger
 from .host import curve as C
 from .host import serialize as S
 from .host.params import P, R, G1_GEN, G2_GEN
@@ -96,16 +96,10 @@ def h2f_device_default(width: int) -> bool:
     return width >= h2f_device_min_n()
 
 
-# Host pack wall time (pack_chunk), process-wide — the `pack` term of the
-# pack|queue|device latency split, delta-able by bench/tools like
-# dispatch_count().  Locked: a multi-group service runs one packer
-# thread per group, and a float += is not atomic.
-_PACK_SECONDS = {"t": 0.0}
-_PACK_LOCK = make_lock()
-
-
 def pack_seconds() -> float:
-    return _PACK_SECONDS["t"]
+    """Process-wide host pack wall time (`pack_chunk`, the `verify.pack`
+    span), delta-able like dispatch_count()."""
+    return metrics.totals().get("verify.pack", (0, 0.0))[1]
 
 
 def chunk_footprint_bytes(pad: int, g2sig: bool) -> int:
@@ -125,12 +119,34 @@ def max_pipeline_depth(pad: int, g2sig: bool) -> int:
         pad, g2sig)))
 
 
-_DISPATCHES = {"n": 0}
-
-
 # (pipeline, argument shapes/dtypes/placements) keys already called in this
 # process: a new key's first call traces and compiles its program
 _RAN: set = set()
+
+# jax compile-path events a first call reports, by their short names
+_FIRST_CALL_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+# .events: {short name: [count, seconds]} of the first call in progress
+# on this thread (jax fires these events on the compiling thread)
+_first_call = threading.local()
+_log = Logger("drand.batch")
+
+
+def _note_event(event, secs, **_kw):
+    events = getattr(_first_call, "events", None)
+    if events is not None:
+        short = _FIRST_CALL_EVENTS.get(event)
+        if short is not None:
+            c = events.setdefault(short, [0, 0.0])
+            c[0] += 1
+            c[1] += secs
+
+
+jax.monitoring.register_event_duration_secs_listener(_note_event)
 
 
 def _arg_key(a):
@@ -138,20 +154,40 @@ def _arg_key(a):
             getattr(a, "sharding", None))
 
 
-def run_program(pipe, *args):
-    """Call a jitted pipeline and count the dispatch.  The first call of
-    a (pipeline, shapes, placements) key runs inside
+def run_program(pipe, *args, name: str = "program"):
+    """Call a jitted pipeline and count the dispatch (`batch.dispatch`).
+    The first call of a (pipeline, shapes, placements) key runs inside
     `device_pool.compiling()`, so the verify service's watchdog spares
-    its compile; dispatch is asynchronous, so the device work itself is
-    judged as usual."""
-    _DISPATCHES["n"] += 1
-    key = (pipe, tuple(_arg_key(a) for a in jax.tree.leaves(args)))
+    its compile, and inside the span `batch.first_call`.  Its flavour,
+    `<name>@<width>` (the leading size of the first argument), gets the
+    span's seconds under `batch.first_call/<flavour>`, the jax trace,
+    lower, compile and cache-load events that fired on this thread
+    inside it under `batch.first_call/<flavour>/<event>`, and one INFO
+    line.  Dispatch is asynchronous, so the device work itself is judged
+    as usual."""
+    metrics.add("batch.dispatch")
+    leaves = jax.tree.leaves(args)
+    key = (pipe, tuple(_arg_key(a) for a in leaves))
     if key in _RAN:
         return pipe(*args)
     from .device_pool import compiling
-    with compiling():
-        out = pipe(*args)
+    width = next((a.shape[0] for a in leaves if getattr(a, "ndim", 0)), 0)
+    flavour = f"{name}@{width}"
+    events = _first_call.events = {}
+    try:
+        with compiling(), metrics.span("batch.first_call",
+                                       flavour=flavour) as s:
+            out = pipe(*args)
+    finally:
+        _first_call.events = None
     _RAN.add(key)
+    metrics.add(f"batch.first_call/{flavour}", s.seconds)
+    for short, (n, secs) in events.items():
+        metrics.add(f"batch.first_call/{flavour}/{short}", secs, count=n)
+    _log.info("first call of a device program", flavour=flavour,
+              seconds=f"{s.seconds:.3f}",
+              **{f"{short}_s": f"{secs:.3f}"
+                 for short, (_, secs) in events.items()})
     return out
 
 
@@ -159,7 +195,7 @@ def dispatch_count() -> int:
     """Process-wide count of jitted device-pipeline invocations issued by
     this module (and crypto/partials.py) — the CPU-backend observability
     hook the one-dispatch-recover acceptance test and bench assert on."""
-    return _DISPATCHES["n"]
+    return metrics.totals().get("batch.dispatch", (0, 0.0))[0]
 
 _NEG_G1 = C.G1.neg(G1_GEN)
 _NEG_G2 = C.G2.neg(G2_GEN)
@@ -567,6 +603,7 @@ class BatchBeaconVerifier:
                  h2f_device: bool | None = None):
         self.scheme = scheme
         self.g2sig = scheme.sig_group is GroupG2
+        self._g = "g2" if self.g2sig else "g1"     # program flavour prefix
         # h2f_device: None = auto (per pad width vs DRAND_H2F_DEVICE_MIN_N);
         # True/False pin the front — the verify service pins per handle so
         # the compiled-program flavor set is fixed at handle creation
@@ -830,7 +867,8 @@ class BatchBeaconVerifier:
         """Dispatch one RLC check (no sync): returns the device-side fused
         verdict scalar."""
         pipe, args = self._rlc_call(enc, n, front)
-        _, all_ok = run_program(pipe, *args)
+        front = self._norm_enc(enc, front)[1]
+        _, all_ok = run_program(pipe, *args, name=f"{self._g}_rlc.{front}")
         return all_ok
 
     def _rlc_ok(self, enc, n, front=None) -> bool:
@@ -845,7 +883,8 @@ class BatchBeaconVerifier:
         pipe = _exact_pipeline_g2sig(front, dst) if self.g2sig \
             else _exact_pipeline_g1sig(front, dst)
         return np.asarray(run_program(pipe, sig_x, sign, msg,
-                                      self.pk_aff, self.fixed_aff))[:n]
+                                      self.pk_aff, self.fixed_aff,
+                                      name=f"{self._g}_exact.{front}"))[:n]
 
     # Below this range size a failed RLC goes straight to exact checks;
     # above it, bisect with RLC halves so one bad round costs O(log n) RLC
@@ -906,17 +945,14 @@ class BatchBeaconVerifier:
         """Stage 1, host side: numpy wire parse + message packing (raw
         message words above the h2f threshold — NO host hashing — else
         the host hash-to-field oracle).  Returns an opaque packed tuple
-        for dispatch/resolve.  Wall time accumulates into
-        `pack_seconds()` — the `pack` term of the pack|queue|device
-        split."""
-        t0 = time.perf_counter()
+        for dispatch/resolve.  Wall time is the `verify.pack` span
+        (`pack_seconds()`)."""
         n = len(rounds)
-        if prev_sigs is None:
-            prev_sigs = [None] * n
-        enc, bad, front = self._pack_enc(rounds, sigs, prev_sigs,
-                                         max(_pad_len(n), self.pad_to or 0))
-        with _PACK_LOCK:
-            _PACK_SECONDS["t"] += time.perf_counter() - t0
+        with metrics.span("verify.pack", round=rounds[0] if n else 0):
+            if prev_sigs is None:
+                prev_sigs = [None] * n
+            enc, bad, front = self._pack_enc(
+                rounds, sigs, prev_sigs, max(_pad_len(n), self.pad_to or 0))
         return (n, enc, bad, front)
 
     def dispatch_packed(self, packed):
@@ -933,8 +969,11 @@ class BatchBeaconVerifier:
         """Stage 3: block on the verdict scalar; bisect to the culprits on
         failure.  Returns the per-round validity array."""
         n, enc, bad, front = packed
-        if verdict is not None and bool(verdict):
-            return np.ones(n, dtype=bool)
+        if verdict is not None:
+            with metrics.span("verify.wait"):
+                ok = bool(verdict)
+            if ok:
+                return np.ones(n, dtype=bool)
         # slow path: bisection + exact checks locate the bad rounds
         return self._verify_range(enc, 0, n, bad, top=True, front=front,
                                   failed=True)
@@ -1062,7 +1101,8 @@ def sign_batch(scheme: Scheme, secret: int, msgs) -> list:
     else:
         u0, u1 = DH.hash_msgs_to_field_g1(pmsgs, scheme.dst)
     bits = DC.scalars_to_bits([secret] * pad, nbits=256)
-    x, y, _ = run_program(_sign_pipeline(g2sig), u0, u1, bits)
+    x, y, _ = run_program(_sign_pipeline(g2sig), u0, u1, bits,
+                          name=f"{'g2' if g2sig else 'g1'}_sign")
     if g2sig:
         pts = _affine_g2_to_host(x, y)
         return [S.g2_to_bytes(pt) for pt in pts[:n]]
@@ -1168,7 +1208,8 @@ def recover_batch(scheme: Scheme, indices, partial_sigs) -> list:
     else:
         sig_x = jnp.asarray(xw.reshape(t, nr, L.NLIMB))
     x, y, dec_ok = run_program(_recover_pipeline(g2sig), sig_x,
-                               jnp.asarray(sgn), bits, neg)
+                               jnp.asarray(sgn), bits, neg,
+                               name=f"{'g2' if g2sig else 'g1'}_recover")
     if not bool(dec_ok):
         # a wire x with no y on the curve — the host decoder's ValueError,
         # detected on device by the shared sqrt scan instead

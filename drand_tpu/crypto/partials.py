@@ -356,7 +356,8 @@ class BatchPartialVerifier:
             _rlc_pipeline(self.g2sig, front, self.scheme.dst),
             sig_x, sign_d, msg, jnp.asarray(_rlc_keys()),
             jnp.asarray(flat_valid.astype(np.uint32)), jnp.asarray(onehot),
-            self._pk_sel(signers), self.fixed_aff)
+            self._pk_sel(signers), self.fixed_aff,
+            name=f"{'g2' if self.g2sig else 'g1'}_partials_rlc.{front}")
         if bool(all_ok):
             return valid
 
@@ -364,5 +365,6 @@ class BatchPartialVerifier:
         pk_slot = self._pk_sel(idxs.reshape(-1))
         got = np.asarray(run_program(
             _exact_pipeline(self.g2sig, front, self.scheme.dst),
-            sig_x, sign_d, msg, pk_slot, self.fixed_aff))
+            sig_x, sign_d, msg, pk_slot, self.fixed_aff,
+            name=f"{'g2' if self.g2sig else 'g1'}_partials_exact.{front}"))
         return got.reshape(r, k) & valid

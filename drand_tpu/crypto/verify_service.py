@@ -77,7 +77,9 @@ lazily on first device-handle request.
 
 import os
 import threading
+import time
 
+from .. import metrics
 from ..common import make_condition, make_lock
 from .device_pool import thread_compiling
 from collections import deque
@@ -136,10 +138,15 @@ STATE_PROBING = "probing"
 _STATE_CODE = {STATE_HEALTHY: 0, STATE_SUSPECT: 1, STATE_DEGRADED: 2,
                STATE_PROBING: 3}
 
-# the submit API's future type: the stdlib one — set_result/set_exception/
-# result(timeout)/done() are exactly the contract the service needs, and
-# callers get cancellation/done-callbacks for free
-VerifyFuture = Future
+class VerifyFuture(Future):
+    """The submit API's future: the stdlib one — set_result/set_exception/
+    result(timeout)/done() are exactly the contract the service needs, and
+    callers get cancellation/done-callbacks for free — plus `verdict_at`,
+    the `time.perf_counter()` at which the scheduler thread had the last
+    device verdict of its batch (None off the pipelined device path): the
+    start of the `verify.return` span."""
+
+    verdict_at: Optional[float] = None
 
 
 class DeviceFailure(RuntimeError):
@@ -190,7 +197,7 @@ class _Batch:
     """One coalesced dispatch unit handed to the executor."""
 
     __slots__ = ("lane", "backend", "requests", "call", "key", "slot",
-                 "stream", "sharded")
+                 "stream", "sharded", "verdict_at")
 
     def __init__(self, lane, backend=None, requests=None, call=None,
                  key=None, slot=None, stream=None, sharded=False):
@@ -202,6 +209,7 @@ class _Batch:
         self.slot = slot
         self.stream: Optional["_GroupStream"] = stream
         self.sharded = sharded
+        self.verdict_at: Optional[float] = None    # see VerifyFuture
 
     @property
     def n(self) -> int:
@@ -353,9 +361,13 @@ class VerifyHandle:
         # guarantees resolution — a hung device dispatch is abandoned at
         # its watchdog deadline and the request requeued to the host
         # fallback, and stop() fails every still-queued future.
+        fut = self.submit(rounds, sigs, prev_sigs, lane=lane, flush_now=True)
         # tpu-vet: disable=wait
-        return self.submit(rounds, sigs, prev_sigs, lane=lane,
-                           flush_now=True).result()
+        out = fut.result()
+        if fut.verdict_at is not None:
+            # verdict on the scheduler thread -> back in the caller's hands
+            metrics.add("verify.return", time.perf_counter() - fut.verdict_at)
+        return out
 
 
 class _PartialLaneVerifier:
@@ -466,8 +478,9 @@ class VerifyService:
         self._dispatches = 0
         self._dispatch_lanes = 0    # sum of real lanes over all dispatches
         self._dispatch_slots = 0    # sum of padded widths over all dispatches
-        self._pack_time = 0.0       # sum of per-chunk host pack wall time
-        self._queue_time = 0.0      # sum of per-batch queue waits (oldest rider)
+        # the process-wide span totals when this service started: the
+        # pack/queue terms of stats() count from here
+        self._spans0 = metrics.totals()
         self._device_time = 0.0     # sum of per-chunk dispatch->verdict time
         self._inflight_max = 0      # deepest in-flight window observed
         self._preemptions = 0
@@ -1118,6 +1131,7 @@ class VerifyService:
                 if exc is not None:
                     r.future.set_exception(exc)
                 else:
+                    r.future.verdict_at = batch.verdict_at
                     r.future.set_result(results[off:off + r.n].copy())
             off += r.n
 
@@ -1214,22 +1228,21 @@ class VerifyService:
             depth = backend.pipeline_depth(depth, pad_width)
 
         def pack(lo, hi):
-            # the pack term of the pack|queue|device latency split: host
-            # wall time spent building the chunk encoding (numpy wire
-            # parse + message packing; with device h2f there is no host
-            # hashing left in here) — observed per chunk, overlapped
-            # with device compute by construction
-            t0 = self.clock.monotonic()
+            # on the packer thread, overlapped with device compute by
+            # construction; the device backend times it (`verify.pack`)
             packed = backend.pack_chunk(
                 rounds[lo:hi], sigs[lo:hi], prevs[lo:hi])
-            self._account_pack(batch.lane, self.clock.monotonic() - t0)
             return lo, hi, packed
 
         def dispatch(item):
             lo, hi, packed = item
+
+            def call():
+                with metrics.span("verify.dispatch", round=rounds[lo]):
+                    return backend.dispatch_packed(packed)
+
             t0 = self.clock.monotonic()
-            d = self._chunk_call(slot, batch,
-                                 lambda: backend.dispatch_packed(packed))
+            d = self._chunk_call(slot, batch, call)
             return lo, hi, packed, d, t0
 
         # Per-chunk device time must be the NON-OVERLAPPED interval: under
@@ -1248,6 +1261,7 @@ class VerifyService:
                 slot, batch, lambda: self._validated(
                     backend.resolve_packed(packed, verdict), hi - lo),
                 scale=window)
+            batch.verdict_at = time.perf_counter()
             end = self.clock.monotonic()
             start = t0 if last_resolved[0] is None \
                 else max(t0, last_resolved[0])
@@ -1942,17 +1956,6 @@ class VerifyService:
             except Exception:
                 pass        # accounting must never cost the dispatch
 
-    def _account_pack(self, lane: str, elapsed: float) -> None:
-        """The pack third of the pack|queue|device latency split: host
-        packing wall time per chunk (packer thread) — the term the
-        device-h2f front shrinks, readable off the same instrumentation
-        as the other two."""
-        from ..metrics import verify_dispatch_latency
-        verify_dispatch_latency.labels(lane, "pack").observe(
-            max(0.0, elapsed))
-        with self._cond:
-            self._pack_time += max(0.0, elapsed)
-
     def _account_queue(self, lane: str, waited: float) -> None:
         """The queue half of the dispatch-latency split: submit-to-gather
         wait of a batch's oldest rider (coalescing window + lane
@@ -1961,8 +1964,7 @@ class VerifyService:
         from ..metrics import verify_dispatch_latency
         verify_dispatch_latency.labels(lane, "queue").observe(
             max(0.0, waited))
-        with self._cond:
-            self._queue_time += max(0.0, waited)
+        metrics.add("verify.queue", max(0.0, waited))
 
     def _stash_sample(self, slot: Optional[_BackendSlot], rounds, sigs,
                       prevs, results, lo: int) -> None:
@@ -1979,6 +1981,12 @@ class VerifyService:
     def stats(self) -> dict:
         pool = self._pool
         groups = pool.snapshot() if pool is not None else {}
+        spans = metrics.totals()
+
+        def since_start(name):
+            return spans.get(name, (0, 0.0))[1] \
+                - self._spans0.get(name, (0, 0.0))[1]
+
         with self._cond:
             for gid, g in groups.items():
                 st = self._streams.get(gid)
@@ -2010,8 +2018,8 @@ class VerifyService:
                 # occupancy observability (ISSUE 10/14): the
                 # pack|queue|device latency split and the deepest
                 # in-flight dispatch window seen
-                "pack_time_s": self._pack_time,
-                "queue_time_s": self._queue_time,
+                "pack_time_s": since_start("verify.pack"),
+                "queue_time_s": since_start("verify.queue"),
                 "device_time_s": self._device_time,
                 "inflight_depth_max": self._inflight_max,
                 "tuning": {s.label: {
@@ -2038,6 +2046,9 @@ class VerifyService:
                                for s in self._slots.values()
                                if s.tenant is not None},
                 "tenant_rebalances": self._tenant_rebalances,
+                # the process-wide program span totals, {name: [count,
+                # seconds]} (metrics.span / metrics.add)
+                "spans": spans,
             }
 
     def set_background_paused(self, paused: bool) -> None:

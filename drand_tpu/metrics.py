@@ -16,8 +16,10 @@ peers with failed partial sends in a sliding one-minute window and
 escalates log severity when failures cross threshold/2 and threshold.
 """
 
+import sys
 import threading
-from typing import Callable, Dict, Optional
+import time
+from typing import Callable, Dict, List, Optional
 
 from prometheus_client import (CollectorRegistry, Counter, Gauge, Histogram,
                                generate_latest)
@@ -151,13 +153,6 @@ integrity_repaired = Counter(
     "chain_integrity_repaired_total",
     "Quarantined/missing rounds re-fetched, re-verified and restored",
     ["beacon_id"], registry=GROUP)
-# TPU-specific: the device batch-verification pipeline.
-batch_verify_rounds = Counter(
-    "tpu_batch_verify_rounds_total", "Beacon rounds verified on device",
-    ["scheme"], registry=PRIVATE)
-batch_verify_seconds = Histogram(
-    "tpu_batch_verify_seconds", "Device batch-verify wall time",
-    ["scheme"], registry=PRIVATE)
 # Resident verify service (crypto/verify_service.py): every verify
 # consumer submits through one daemon-owned pipeline; these series answer
 # "is coalescing working" (fill ratio up, dispatches well below requests)
@@ -182,13 +177,11 @@ verify_fill_ratio = Histogram(
     registry=PRIVATE)
 verify_dispatch_latency = Histogram(
     "verify_service_dispatch_latency_seconds",
-    "Verify-service latency split: phase=pack is host chunk-packing wall "
-    "time (numpy wire parse + message packing; the term device "
-    "hash-to-field removes the hashing from), phase=queue is "
-    "submit-to-gather wait (coalescing window + lane contention, per "
-    "batch), phase=device is dispatch-to-verdict wall time (per coalesced "
-    "chunk) — occupancy regressions show up as device-time growth, "
-    "overload as queue growth, host-bound packing as pack growth",
+    "Verify-service latency split: phase=queue is submit-to-gather wait "
+    "(coalescing window + lane contention, per batch), phase=device is "
+    "dispatch-to-verdict wall time (per coalesced chunk) — occupancy "
+    "regressions show up as device-time growth, overload as queue "
+    "growth; host packing is drand_span_seconds_total{span=\"verify.pack\"}",
     ["lane", "phase"], registry=PRIVATE)
 verify_inflight = Gauge(
     "verify_service_inflight_depth",
@@ -353,6 +346,77 @@ authz_tokens = Counter(
     "authz_tokens_total",
     "Tenant-token lifecycle events (minted | revoked)",
     ["event"], registry=PRIVATE)
+
+
+# -- program spans and counters ---------------------------------------------
+# One in-memory registry of the program's own timing, kept where the work
+# happens (the scanner's store reads, the verify service's pack, dispatch
+# and verdict, a device program's first call): each name holds [count,
+# host seconds].  VerifyService.stats()["spans"] carries a snapshot (the
+# benchmark deltas two of them), and /metrics exports the seconds.
+
+span_seconds = Counter(
+    "drand_span_seconds_total",
+    "Host seconds inside each program span (metrics.span / metrics.add)",
+    ["span"], registry=PRIVATE)
+
+_spans: Dict[str, list] = {}     # name -> [count, seconds, prometheus child]
+_spans_lock = threading.Lock()
+
+
+def add(name: str, seconds: float = 0.0, count: int = 1) -> None:
+    """Count `count` `name` events of `seconds` in all (0 for a plain
+    count).  For an interval measured across threads; it writes no trace
+    event."""
+    with _spans_lock:
+        t = _spans.get(name)
+        if t is None:
+            t = _spans[name] = [0, 0.0, None]
+        t[0] += count
+        t[1] += seconds
+        if seconds > 0 and t[2] is None:
+            t[2] = span_seconds.labels(
+                registered_label(name, ns="span", limit=128))
+        child = t[2]
+    if child is not None and seconds > 0:
+        child.inc(seconds)
+
+
+def totals() -> Dict[str, List]:
+    """Snapshot: {name: [count, seconds]}."""
+    with _spans_lock:
+        return {k: [t[0], t[1]] for k, t in _spans.items()}
+
+
+class span:
+    """`with span(name, **ids):` adds the block's host time to `name`
+    (see `add`).  Where jax is loaded it also opens a
+    `jax.profiler.TraceAnnotation(name, **ids)`, so a profiled run shows
+    the block on the device trace's clock; `ids` (a chunk's first round,
+    a program flavour) link the spans of one piece of work there.  Never
+    used inside a traced function."""
+
+    __slots__ = ("name", "seconds", "_ann", "_t0")
+
+    def __init__(self, name: str, **ids):
+        self.name = name
+        self.seconds = 0.0
+        jax = sys.modules.get("jax")
+        self._ann = jax.profiler.TraceAnnotation(name, **ids) \
+            if jax is not None else None
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self._t0
+        add(self.name, self.seconds)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
 
 
 def scrape(which: str = "group") -> bytes:

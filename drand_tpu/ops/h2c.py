@@ -346,7 +346,7 @@ def map_to_g2_jac(u):
 # counter instead of a timing.
 # ---------------------------------------------------------------------------
 
-# Locked like batch._PACK_SECONDS: host-front handles on a multi-group
+# Locked: host-front handles on a multi-group
 # service hash from one packer thread per group, and += is not atomic.
 _HOST_H2F = {"n": 0}
 _HOST_H2F_LOCK = threading.Lock()

@@ -561,7 +561,7 @@ def _pow_call(e: int, btot: int):
         out_specs=_DATA_SPEC,
     )
     return pl.pallas_call(
-        kernel, grid_spec=gs,
+        kernel, grid_spec=gs, name="fp_pow_kernel",
         out_shape=jax.ShapeDtypeStruct((NL, btot), U32))
 
 
@@ -604,7 +604,7 @@ def _pow2_call(e: int, btot: int):
         out_specs=[_DATA_SPEC, _DATA_SPEC],
     )
     return pl.pallas_call(
-        kernel, grid_spec=gs,
+        kernel, grid_spec=gs, name="fp2_pow_kernel",
         out_shape=[jax.ShapeDtypeStruct((NL, btot), U32)] * 2)
 
 
@@ -640,7 +640,7 @@ def _ladder_var_call(kind: str, nbits: int, btot: int):
         out_specs=[spec] * nc,
     )
     return pl.pallas_call(
-        kernel, grid_spec=gs,
+        kernel, grid_spec=gs, name=f"{kind.lower()}_ladder_kernel",
         out_shape=[jax.ShapeDtypeStruct((NL, btot), U32)] * nc)
 
 
@@ -680,7 +680,7 @@ def _ladder_fixed_call(kind: str, k: int, btot: int):
         out_specs=[spec] * nc,
     )
     return pl.pallas_call(
-        kernel, grid_spec=gs,
+        kernel, grid_spec=gs, name=f"{kind.lower()}_ladder_fixed_kernel",
         out_shape=[jax.ShapeDtypeStruct((NL, btot), U32)] * nc)
 
 
@@ -1156,7 +1156,7 @@ def _miller_call(btot: int):
         out_specs=[spec] * 12,
     )
     return pl.pallas_call(
-        kernel, grid_spec=gs,
+        kernel, grid_spec=gs, name="miller_loop_kernel",
         out_shape=[jax.ShapeDtypeStruct((NL, btot), U32)] * 12)
 
 
@@ -1193,7 +1193,7 @@ def _finalexp_call(btot: int):
         out_specs=[spec] * 12,
     )
     return pl.pallas_call(
-        kernel, grid_spec=gs,
+        kernel, grid_spec=gs, name="final_exp_kernel",
         out_shape=[jax.ShapeDtypeStruct((NL, btot), U32)] * 12)
 
 
@@ -1299,7 +1299,7 @@ def _sum_call(kind: str, btot: int):
         out_specs=[spec] * nc,
     )
     return pl.pallas_call(
-        kernel, grid_spec=gs,
+        kernel, grid_spec=gs, name=f"{kind.lower()}_point_sum_kernel",
         out_shape=[jax.ShapeDtypeStruct((NL, btot), U32)] * nc)
 
 
@@ -1416,7 +1416,7 @@ def _ladder_glv_mixed_call(kind: str, nbits: int, btot: int):
         out_specs=[spec] * nc,
     )
     return pl.pallas_call(
-        kernel, grid_spec=gs,
+        kernel, grid_spec=gs, name=f"{kind.lower()}_glv_ladder_kernel",
         out_shape=[jax.ShapeDtypeStruct((NL, btot), U32)] * nc)
 
 

@@ -625,6 +625,7 @@ class CompilingBackend(StubBackend):
 
     def verify_batch(self, rounds, sigs, prev_sigs=None):
         from drand_tpu.crypto.batch import run_program
+        self.thread = threading.get_ident()
         self.started.set()
         run_program(self.prog, jnp.ones(len(rounds)))
         return super().verify_batch(rounds, sigs, prev_sigs)
@@ -639,6 +640,13 @@ def test_watchdog_spares_a_compiling_dispatch():
     h = svc.handle(SCHEME, PK, backend=dev, fallback=fb)
     f = h.submit(*beacons([1, 2]), lane=LANE_LIVE)
     assert dev.started.wait(10)
+    # the thread is marked only once run_program is inside its first call:
+    # advance the clock past the floor no earlier than that
+    from drand_tpu.crypto.device_pool import thread_compiling
+    deadline = time.monotonic() + 10
+    while not thread_compiling(dev.thread) and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert thread_compiling(dev.thread)
     for _ in range(3):
         svc.clock.advance(11.0)
         time.sleep(0.2)             # several watchdog polls
