@@ -194,7 +194,8 @@ class Config:
                 probe_interval=self.verify_probe_interval or None,
                 pipeline_depth=self.verify_pipeline_depth,
                 device_groups=self.verify_device_groups,
-                shard_threshold=self.verify_shard_threshold)
+                shard_threshold=self.verify_shard_threshold,
+                sync_chunk=self.sync_chunk)
             # a service created while the admission ladder already has
             # background work paused must start paused, not race a level
             # change it never saw

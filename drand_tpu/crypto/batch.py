@@ -600,7 +600,7 @@ class BatchBeaconVerifier:
 
     def __init__(self, scheme: Scheme, public_key_bytes: bytes,
                  pad_to: int | None = None, sharding=None, devices=None,
-                 h2f_device: bool | None = None):
+                 h2f_device: bool | None = None, widths=None):
         self.scheme = scheme
         self.g2sig = scheme.sig_group is GroupG2
         self._g = "g2" if self.g2sig else "g1"     # program flavour prefix
@@ -613,6 +613,12 @@ class BatchBeaconVerifier:
         # pads every config to 8192: compile count is the scarce resource
         # on-chip, and pad slots cost ~linear device time but zero compiles)
         self.pad_to = pad_to
+        # widths: the lane widths a batch may dispatch at (the verify
+        # service's crypto/tuning.lane_widths, topped by pad_to); a batch
+        # takes the smallest that holds it (lane_width).  Default: the one
+        # width pad_to.
+        self.widths = tuple(sorted(widths)) if widths \
+            else (pad_to,) if pad_to else ()
         # sharding: optional persistent placement over the round axis,
         # owned by the caller (the verify service's device pool builds ONE
         # mesh per scope); devices: an explicit device group this verifier
@@ -635,6 +641,13 @@ class BatchBeaconVerifier:
                            (L.encode_mont(self.pub_point[1][0]), L.encode_mont(self.pub_point[1][1])))
             self.fixed_aff = ((L.encode_mont(_NEG_G2[0][0]), L.encode_mont(_NEG_G2[0][1])),
                               (L.encode_mont(_NEG_G2[1][0]), L.encode_mont(_NEG_G2[1][1])))
+
+    def lane_width(self, n: int) -> int:
+        """Lanes a batch of n rounds is packed and dispatched at: the
+        smallest of `widths` that holds the next power of two, or that
+        power of two when it is wider than every one of them."""
+        need = _pad_len(n)
+        return next((w for w in self.widths if w >= need), need)
 
     # -- host-side packing ---------------------------------------------------
 
@@ -901,9 +914,9 @@ class BatchBeaconVerifier:
         """Verdicts for rounds [lo, hi); `failed` = the RLC over this
         range already came back false (skip re-running it)."""
         n = hi - lo
-        # top level: use the batch encoding at its full pad (which may
-        # exceed _pad_len(n) when pad_to is set — sharing one compiled
-        # program shape across chains)
+        # top level: use the batch encoding at its full width (which may
+        # exceed _pad_len(n) — the lane_width it was packed at, sharing
+        # one compiled program shape across chunks and chains)
         sub = enc if top else self._slice_enc(enc, lo, hi,
                                               self._leaf_len(enc))
         if not failed and not bad[lo:hi].any() \
@@ -933,7 +946,7 @@ class BatchBeaconVerifier:
         if prev_sigs is None:
             prev_sigs = [None] * n
         enc, bad, front = self._pack_enc(rounds, sigs, prev_sigs,
-                                         max(_pad_len(n), self.pad_to or 0))
+                                         self.lane_width(n))
         return self._verify_range(enc, 0, n, bad, top=True, front=front)
 
     # -- pack / dispatch / resolve: the double-buffer triple -----------------
@@ -952,7 +965,7 @@ class BatchBeaconVerifier:
             if prev_sigs is None:
                 prev_sigs = [None] * n
             enc, bad, front = self._pack_enc(
-                rounds, sigs, prev_sigs, max(_pad_len(n), self.pad_to or 0))
+                rounds, sigs, prev_sigs, self.lane_width(n))
         return (n, enc, bad, front)
 
     def dispatch_packed(self, packed):
@@ -984,7 +997,7 @@ class BatchBeaconVerifier:
         footprint so depth x chunk bytes stays under the in-flight budget
         (depth cannot blow device memory no matter what the knob says)."""
         want = depth if depth is not None else DEFAULT_PIPELINE_DEPTH
-        pad = max(_pad_len(chunk_size), self.pad_to or 0)
+        pad = self.lane_width(chunk_size)
         return max(1, min(int(want), max_pipeline_depth(pad, self.g2sig)))
 
     def verify_stream(self, beacons, chunk_size: int = 8192, depth=None):

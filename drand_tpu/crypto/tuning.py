@@ -18,6 +18,22 @@ Precedence (each knob independently):
   4. the defaults: pad 8192, depth 1 (today's behavior — a container
      with no chip and no tuning file changes nothing).
 
+The pad is the coalescing target: the service gathers up to `pad` rounds
+of a chain into one batch.  The LANE WIDTH a batch is dispatched at is
+fitted to the traffic the handle is known to get (`lane_widths`): a
+default or TUNING.json pad, on a one-device group, gives the handle two
+widths, the power of two that holds the chunk its owner's scanners and
+sync submit (`Config.sync_chunk`, 512: the service's `sync_chunk`) and
+the pad itself, and each dispatch runs at the smaller one that holds it.
+A 512-round scan chunk then runs a 512-lane pass, not an 8192-lane one
+of which 15/16 is padding; fills above the chunk run at the pad, as
+before.  Each width is its own compiled program, compiled lazily on its
+first dispatch, so traffic that only ever fills one width compiles one.
+A PIN (the explicit value or the env override) means ONE width: the
+handle dispatches every batch at the pinned pad, as before.  So does a
+service told no chunk, and a handle on a group of several devices,
+whose batch is split across them.
+
 File shape::
 
     {"version": 1,
@@ -45,6 +61,8 @@ from typing import Optional, Tuple
 
 DEFAULT_PAD = 8192
 DEFAULT_DEPTH = 1
+# no width below the Pallas kernels' batch tile (ops/pallas_field.TILE)
+MIN_WIDTH = 256
 TUNING_BASENAME = "TUNING.json"
 
 _lock = make_lock()
@@ -108,14 +126,15 @@ def _env_int(name: str) -> Optional[int]:
 def resolve(kind: str, platform: str,
             pad: Optional[int] = None,
             depth: Optional[int] = None,
-            group_size: int = 1) -> Tuple[int, int, str]:
-    """(pad, depth, source) for a verify handle of `kind` ("g1" | "g2")
-    on `platform` (jax.default_backend(): "tpu" | "cpu" | ...) whose
+            group_size: int = 1) -> Tuple[int, int, str, bool]:
+    """(pad, depth, source, pinned) for a verify handle of `kind` ("g1" |
+    "g2") on `platform` (jax.default_backend(): "tpu" | "cpu" | ...) whose
     device group owns `group_size` devices.  Explicit args pin; env
     overrides beat the file; the file must match the CURRENT platform
     (a chip sweep's numbers never apply to the CPU fallback container)
     and prefers the `<kind>@<group_size>` entry over the bare `<kind>`
-    fallback; otherwise the 8192x1 defaults."""
+    fallback; otherwise the 8192x1 defaults.  `pinned`: the pad came
+    from an explicit arg or the env override."""
     src_pad = src_depth = "default"
     out_pad, out_depth = DEFAULT_PAD, DEFAULT_DEPTH
     plat_entries = load_entries().get(platform, {})
@@ -139,7 +158,20 @@ def resolve(kind: str, platform: str,
         out_pad, src_pad = int(pad), "explicit"
     if depth:
         out_depth, src_depth = int(depth), "explicit"
-    return out_pad, out_depth, f"pad:{src_pad},depth:{src_depth}"
+    return (out_pad, out_depth, f"pad:{src_pad},depth:{src_depth}",
+            src_pad in ("explicit", "env"))
+
+
+def lane_widths(pad: int, pinned: bool,
+                chunk: Optional[int] = None) -> Tuple[int, ...]:
+    """The lane widths, ascending, of a one-device handle: (pad,) when
+    the pad is pinned or no chunk is known; else the power of two that
+    holds `chunk`, when it is at least MIN_WIDTH and under the pad, and
+    the pad."""
+    low = 1 << (int(chunk) - 1).bit_length() if chunk else pad
+    if pinned or not MIN_WIDTH <= low < pad:
+        return (pad,)
+    return (low, pad)
 
 
 def write_tuning(path: str, platform: str, results: dict) -> None:

@@ -429,7 +429,7 @@ class VerifyService:
                  pipeline_depth: int = 0,
                  device_groups: int = 0,
                  shard_threshold: int = 0,
-                 pool=None):
+                 pool=None, sync_chunk: int = 0):
         if clock is None:
             # deferred import: crypto must not hard-depend on beacon at
             # module scope (same layering softening as net/resilience.py)
@@ -441,6 +441,10 @@ class VerifyService:
         self.pad_override = max(0, int(pad or 0))
         self.depth_override = max(0, int(pipeline_depth or 0))
         self.pad = self.pad_override or DEFAULT_PAD
+        # the chunk the owner's scanners and sync submit (Config.sync_chunk):
+        # an unpinned one-device handle dispatches such chunks at their own
+        # width (tuning.lane_widths); 0 = unknown, one width
+        self.sync_chunk = max(0, int(sync_chunk or 0))
         self.windows = {LANE_LIVE: live_window,
                         LANE_BACKGROUND: background_window}
         self.watchdog_factor = watchdog_factor or DEFAULT_WATCHDOG_FACTOR
@@ -478,6 +482,7 @@ class VerifyService:
         self._dispatches = 0
         self._dispatch_lanes = 0    # sum of real lanes over all dispatches
         self._dispatch_slots = 0    # sum of padded widths over all dispatches
+        self._dispatch_widths: Dict[int, int] = {}  # packed width -> count
         # the process-wide span totals when this service started: the
         # pack/queue terms of stats() count from here
         self._spans0 = metrics.totals()
@@ -551,7 +556,7 @@ class VerifyService:
         # dispatch on the group's devices, and counting them would push
         # real device chains off otherwise-empty groups
         group = pool.assign(key, weigh=(kind != "host"), **hints)
-        pad, depth = self._tuned(scheme, max(1, group.n_devices))
+        pad, depth, _ = self._tuned(scheme, max(1, group.n_devices))
         factory = backend_factory
         if backend is None and factory is None and kind == "device":
             # pin to the group's devices only when there is more than one
@@ -563,16 +568,17 @@ class VerifyService:
 
             def factory(g, s=scheme, p=pk, pin=pin):
                 from .batch import BatchBeaconVerifier, h2f_device_default
-                fpad, _ = self._tuned(s, max(1, g.n_devices))
+                fpad, _, widths = self._tuned(s, max(1, g.n_devices))
                 # the group's placement is built once and shared by
                 # every chain on the group (DeviceGroup.sharding caches);
                 # the hash-to-field front is PINNED per handle (ISSUE
                 # 14): at/above DRAND_H2F_DEVICE_MIN_N the pack path
                 # ships raw message bytes and the digest + xmd + h2f
-                # chain runs inside the verify dispatch — one compiled
-                # flavor per handle, fixed at creation
+                # chain runs inside the verify dispatch — one front
+                # flavor per handle, fixed at creation from the pad and
+                # shared by each of the handle's lane widths
                 return BatchBeaconVerifier(
-                    s, p, pad_to=fpad,
+                    s, p, pad_to=fpad, widths=widths,
                     sharding=g.sharding() if pin else None,
                     h2f_device=h2f_device_default(fpad))
         if backend is None:
@@ -683,8 +689,8 @@ class VerifyService:
             new_backend = slot.backend_factory(group)
         except BaseException:
             return False
-        pad, depth = self._tuned(slot.scheme, max(1, group.n_devices)) \
-            if slot.scheme is not None else (slot.pad, slot.depth)
+        pad, depth, _ = self._tuned(slot.scheme, max(1, group.n_devices)) \
+            if slot.scheme is not None else (slot.pad, slot.depth, ())
         with self._cond:
             slot.primary = new_backend
             slot.gid = group.gid
@@ -742,26 +748,31 @@ class VerifyService:
             else "host"
 
     def _tuned(self, scheme, group_size: int = 1):
-        """(pad, depth) for a new handle: explicit ctor overrides pin;
-        otherwise env > TUNING.json (current platform + scheme kind AT
-        THIS GROUP SIZE — `kind@n` entries beat the bare-kind fallback,
-        so a 1-device and a 4-device group resolve independently) > the
-        8192x1 defaults.  Platform detection (a jax touch) is skipped
-        when nothing could override anyway."""
+        """(pad, depth, lane widths) for a new handle: explicit ctor
+        overrides pin; otherwise env > TUNING.json (current platform +
+        scheme kind AT THIS GROUP SIZE — `kind@n` entries beat the
+        bare-kind fallback, so a 1-device and a 4-device group resolve
+        independently) > the 8192x1 defaults.  A pinned pad is one lane
+        width; an unpinned one on a one-device group adds the width of
+        `sync_chunk` (tuning.lane_widths).  Platform detection (a jax
+        touch) is skipped when nothing could override anyway."""
         from . import tuning
-        if self.pad_override and self.depth_override:
-            return self.pad_override, self.depth_override
         sig_group = getattr(scheme, "sig_group", None)
         kind = "g2" if getattr(sig_group, "__name__", "") == "GroupG2" \
             else "g1"
-        consult = tuning.tuning_path() is not None \
-            or os.environ.get("DRAND_VERIFY_PAD") \
-            or os.environ.get("DRAND_VERIFY_PIPELINE_DEPTH")
+        consult = not (self.pad_override and self.depth_override) and (
+            tuning.tuning_path() is not None
+            or os.environ.get("DRAND_VERIFY_PAD")
+            or os.environ.get("DRAND_VERIFY_PIPELINE_DEPTH"))
         platform = self._get_pool().platform if consult else "cpu"
-        pad, depth, _src = tuning.resolve(
+        pad, depth, _src, pinned = tuning.resolve(
             kind, platform, pad=self.pad_override or None,
             depth=self.depth_override or None, group_size=group_size)
-        return pad, depth
+        # a group of several devices splits each batch across them: one
+        # width, as for a pin
+        widths = tuning.lane_widths(pad, pinned or group_size > 1,
+                                    self.sync_chunk)
+        return pad, depth, widths
 
     def _pad_of(self, key) -> int:
         """Coalescing width for a handle key (caller holds the lock or
@@ -1221,6 +1232,9 @@ class VerifyService:
         from ..metrics import verify_inflight
         packer = self._ensure_packer(batch.stream)
         pad_width = max(span_pad, getattr(backend, "pad_to", 0) or 0)
+        # the lanes each chunk is packed at: the backend fits its width to
+        # the chunk's fill (BatchBeaconVerifier.lane_width)
+        lane_width = getattr(backend, "lane_width", lambda n: pad_width)
         depth = max(1, slot.depth if slot is not None else 1)
         if hasattr(backend, "pipeline_depth"):
             # the backend clamps by per-chunk footprint: depth x chunk
@@ -1266,8 +1280,9 @@ class VerifyService:
             start = t0 if last_resolved[0] is None \
                 else max(t0, last_resolved[0])
             last_resolved[0] = end
-            self._account(batch.lane, hi - lo, pad_width, end - start,
-                          slot=slot, gid=batch.gid, sharded=batch.sharded)
+            self._account(batch.lane, hi - lo, lane_width(hi - lo),
+                          end - start, slot=slot, gid=batch.gid,
+                          sharded=batch.sharded, packed=True)
             self._stash_sample(slot, rounds, sigs, prevs, results, lo)
 
         inflight: deque = deque()
@@ -1496,7 +1511,8 @@ class VerifyService:
             # group — put the pool affinity back so loads/stats agree
             self._pool.place(slot.key, old_gid)
             return False
-        pad, depth = self._tuned(slot.scheme, max(1, sibling.n_devices))
+        pad, depth, _ = self._tuned(slot.scheme,
+                                    max(1, sibling.n_devices))
         with self._cond:
             slot.primary = new_backend
             slot.gid = sibling.gid
@@ -1923,7 +1939,11 @@ class VerifyService:
 
     def _account(self, lane: str, lanes: int, slots: int,
                  elapsed: float, slot: Optional[_BackendSlot] = None,
-                 gid: Optional[int] = None, sharded: bool = False) -> None:
+                 gid: Optional[int] = None, sharded: bool = False,
+                 packed: bool = False) -> None:
+        """One dispatch of `lanes` real rounds in `slots` lanes; a
+        `packed` (device) dispatch also counts under its lane width in
+        `stats()["dispatch_widths"]`."""
         from ..metrics import (verify_dispatch_latency, verify_dispatches,
                                verify_fill_ratio)
         verify_dispatches.labels(lane, str(gid if gid is not None
@@ -1935,6 +1955,9 @@ class VerifyService:
             self._dispatches += 1
             self._dispatch_lanes += lanes
             self._dispatch_slots += slots
+            if packed:
+                self._dispatch_widths[slots] = \
+                    self._dispatch_widths.get(slots, 0) + 1
             self._device_time += max(0.0, elapsed)
             if sharded:
                 self._sharded_dispatches += 1
@@ -2015,6 +2038,8 @@ class VerifyService:
                 # (bench config 6) instead of blending cold+warm runs
                 "dispatch_lanes": self._dispatch_lanes,
                 "dispatch_slots": self._dispatch_slots,
+                # packed dispatches per lane width (tuning.lane_widths)
+                "dispatch_widths": dict(self._dispatch_widths),
                 # occupancy observability (ISSUE 10/14): the
                 # pack|queue|device latency split and the deepest
                 # in-flight dispatch window seen

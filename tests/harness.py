@@ -7,6 +7,7 @@ DKG-produced shares are exercised by the dkg tests instead."""
 import threading
 import time
 
+from drand_tpu import metrics
 from drand_tpu.beacon import FakeClock, Handler, HandlerConfig
 from drand_tpu.chain import MemDBStore
 from drand_tpu.crypto import tbls
@@ -28,6 +29,41 @@ SERVICE_THREAD_PREFIXES = ("verify-scheduler", "verify-packer",
 # pool — request traffic must never grow this set (the unbounded
 # ThreadingHTTPServer thread-per-request bug this replaces)
 REST_THREAD_PREFIXES = ("rest-edge", "rest-worker", "http-relay")
+
+
+class OwnWork:
+    """The registry events of a test's own work.  The registry is
+    process-wide, and threads that earlier tests left behind in the same
+    process (a test worker runs many files) may add to it meanwhile.  From
+    construction on, every `metrics.add` (a span's exit too) is recorded
+    with whether it came from the constructing thread or a thread started
+    after construction (the test's service threads): `delta(since)` sums
+    those alone, `delta(since, own=False)` every thread's."""
+
+    def __init__(self, monkeypatch):
+        here = threading.current_thread()
+        theirs = set(threading.enumerate()) - {here}
+        self.events = []
+        add = metrics.add
+
+        def recording(name, seconds=0.0, count=1):
+            add(name, seconds, count)
+            own = threading.current_thread() not in theirs
+            self.events.append((own, name, count, seconds))
+
+        monkeypatch.setattr(metrics, "add", recording)
+
+    def mark(self) -> int:
+        return len(self.events)
+
+    def delta(self, since=0, own=True):
+        out = {}
+        for mine, name, n, secs in self.events[since:]:
+            if mine or not own:
+                c = out.setdefault(name, [0, 0.0])
+                c[0] += n
+                c[1] += secs
+        return out
 
 
 def service_threads():

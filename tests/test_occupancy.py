@@ -272,9 +272,10 @@ def test_no_file_no_env_is_todays_default(monkeypatch):
     monkeypatch.delenv("DRAND_VERIFY_PAD", raising=False)
     monkeypatch.delenv("DRAND_VERIFY_PIPELINE_DEPTH", raising=False)
     monkeypatch.chdir("/tmp")                  # no cwd TUNING.json
-    pad, depth, src = tuning.resolve("g2", "cpu")
+    pad, depth, src, pinned = tuning.resolve("g2", "cpu")
     assert (pad, depth) == (DEFAULT_PAD, 1)
     assert src == "pad:default,depth:default"
+    assert not pinned
 
 
 def test_tuning_resolve_platform_scoped(tmp_path, monkeypatch):

@@ -464,6 +464,135 @@ def test_device_backend_gets_group_placement_and_pool_sharding():
     svc.stop()
 
 
+# -- lane widths fitted to each dispatch's fill -------------------------------
+
+
+class OneLineRLC:
+    """Builds the service's device backend, `BatchBeaconVerifier` with
+    one-line programs for its RLC pass and its exact checks: a lane fails
+    iff its round is `bad`, and lanes past n are masked as in the real
+    pass.  The service's own factory calls it, with the pad, widths and
+    front it chose, so no pairing program compiles."""
+
+    def __init__(self, name, bad=0):
+        from drand_tpu.crypto import batch
+        self.name, self.bad = name, bad
+        self.real = batch.BatchBeaconVerifier
+        self.built = []
+
+    def __call__(self, *args, **kwargs):
+        from drand_tpu.crypto import batch
+        name, bad = self.name, jnp.uint32(self.bad)
+        rlc = jax.jit(lambda rw, n, bad: jnp.all(
+            (jnp.arange(rw.shape[0], dtype=jnp.uint32) >= n)
+            | (rw[:, 1] != bad)))
+        exact = jax.jit(lambda rw, bad: rw[:, 1] != bad)
+
+        class Backend(self.real):
+            # the raw_unchained front's message is (round words,)
+            def _rlc_dispatch(self, enc, n, front=None):
+                return batch.run_program(rlc, enc[2][0], jnp.uint32(n), bad,
+                                         name=f"{name}_rlc.{front}")
+
+            def _exact(self, enc, n, front=None):
+                return np.asarray(batch.run_program(
+                    exact, enc[2][0], bad, name=f"{name}_exact"))[:n]
+
+        backend = Backend(*args, **kwargs)
+        self.built.append(backend)
+        return backend
+
+
+FILLS = (1, 100, 512, 513, 2048, 2049, 8192)
+ABOVE = (8192,) * 4         # fills past the chunk run at the pad, as before
+
+
+@pytest.mark.parametrize("pin,chunk,fills,widths,bad", [
+    ("default", 512, FILLS, (512,) * 3 + ABOVE, 0),
+    ("tuning", 512, FILLS, (512,) * 3 + ABOVE, 0),
+    # the low width is the chunk's own power of two
+    ("default", 1024, (512, 1024, 1025), (1024, 1024, 8192), 0),
+    # no chunk known, or under the Pallas tile: one width
+    ("default", 0, (1, 512), (8192, 8192), 0),
+    ("default", 128, (1, 128), (8192, 8192), 0),
+    ("ctor", 512, FILLS, (8192,) * 7, 0),
+    ("env", 512, FILLS, (8192,) * 7, 0),
+    ("config", 512, (1, 512, 8192), (8192,) * 3, 0),
+    # an unpinned Config passes its sync_chunk to the service
+    ("config_auto", 512, (1, 512, 513), (512, 512, 8192), 0),
+    # a group of several devices splits each batch: one width
+    ("group", 512, (1, 512), (8192, 8192), 0),
+    ("default", 512, (512,) * 4, (512,) * 4, 0),
+    # the third chunk fails: bisection runs at its 512 lanes, down to
+    # one 64-lane exact leaf, and first-calls no other RLC width
+    ("default", 512, (512,) * 4, (512,) * 4, 1100),
+])
+def test_dispatch_width_fits_the_fill(pin, chunk, fills, widths, bad,
+                                      tmp_path, monkeypatch):
+    from collections import Counter
+
+    from drand_tpu.core.config import Config
+    from drand_tpu.crypto import batch, schemes
+    from drand_tpu.crypto.device_pool import jax_devices
+    from drand_tpu.crypto.host import serialize
+    from drand_tpu.crypto.host.params import G1_GEN
+    from harness import OwnWork
+
+    if pin == "group" and len(jax_devices()) < 2:
+        pytest.skip("needs a multi-device (virtual CPU) mesh")
+    for var in ("DRAND_VERIFY_PAD", "DRAND_TUNING_FILE", "DRAND_H2F_DEVICE",
+                "DRAND_H2F_DEVICE_MIN_N"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.chdir(tmp_path)                 # no ./TUNING.json
+    if pin == "tuning":
+        tf = tmp_path / "TUNING.json"
+        tf.write_text('{"version": 1, "entries": {"%s": {"g1": '
+                      '{"pad": 8192, "depth": 1}}}}' % jax.default_backend())
+        monkeypatch.setenv("DRAND_TUNING_FILE", str(tf))
+    if pin == "env":
+        monkeypatch.setenv("DRAND_VERIFY_PAD", "8192")
+    name = f"widths_{pin}_{chunk}_{len(fills)}_{bad}"
+    build = OneLineRLC(name, bad)
+    monkeypatch.setattr(batch, "BatchBeaconVerifier", build)
+    work = OwnWork(monkeypatch)
+    if pin.startswith("config"):
+        svc = Config(folder=str(tmp_path / "daemon"), sync_chunk=chunk,
+                     verify_pad=8192 if pin == "config" else 0,
+                     verify_window=0.0).verify_service()
+    else:
+        svc = VerifyService(pad=8192 if pin == "ctor" else 0,
+                            background_window=0.0, sync_chunk=chunk,
+                            device_groups=1 if pin == "group" else 0)
+    sch = schemes.scheme_from_name("bls-unchained-g1-rfc9380")
+    pk = sch.public_bytes(sch.keypair(seed=b"widths")[1])
+    sig = serialize.g1_to_bytes(G1_GEN)
+    try:
+        h = svc.handle(sch, pk, device=True)
+        assert build.built and h.backend is build.built[0]
+        assert svc._pool.group(h.gid).n_devices == \
+            (len(jax_devices()) if pin == "group" else 1)
+        lo = 1
+        for n in fills:
+            rounds = list(range(lo, lo + n))
+            got = h.verify_batch(rounds, [sig] * n)
+            assert (got == np.array([r != bad for r in rounds])).all()
+            lo += n
+        st = svc.stats()
+    finally:
+        svc.stop()
+    assert st["dispatch_widths"] == dict(Counter(widths))
+    assert st["fill_ratio"] == pytest.approx(sum(fills) / sum(widths))
+    d = work.delta()
+    firsts = sorted(k for k in d if k.startswith("batch.first_call/")
+                    and k.count("/") == 1)
+    want = [f"batch.first_call/{name}_rlc.raw_unchained@{w}"
+            for w in set(widths)]
+    if bad:
+        want.append(f"batch.first_call/{name}_exact@64")
+    assert firsts == sorted(want)
+    assert all(d[f][0] == 1 for f in firsts)
+
+
 # -- the device failure domain ------------------------------------------------
 # watchdog deadlines, retry-once + atomic failover, requeue-not-fail,
 # canary re-promotion, per-chunk error containment (ISSUE 7)
