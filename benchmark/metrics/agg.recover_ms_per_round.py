@@ -1,0 +1,20 @@
+"""Host milliseconds a stored round spent in Lagrange recovery of the
+full signature from t verified partials (the program's `agg.recover`
+span around `tbls.recover`) over the window's rounds.
+
+The delta of the span's seconds between the verify service's
+`stats()["spans"]` snapshots before and after the window; nothing where
+the program keeps no such span."""
+
+SPAN = "agg.recover"
+
+
+def read(rec):
+    s0, s1 = rec["stats0"].get("spans"), rec["stats1"].get("spans")
+    if s0 is None or s1 is None or SPAN not in s1 or not rec["rounds"]:
+        return None
+    n0, t0 = s0.get(SPAN, (0, 0.0))
+    n1, t1 = s1[SPAN]
+    if n1 == n0:
+        return None
+    return (t1 - t0) * 1e3 / rec["rounds"]

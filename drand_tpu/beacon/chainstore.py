@@ -6,11 +6,17 @@ reference's `runAggregator` goroutine).  When a (round, prev_sig) cache
 reaches the group threshold it Lagrange-recovers the full signature
 (tbls.Recover, chainstore.go:202), verifies it against the collective key
 (chainstore.go:207) and appends through the decorator chain; the cache is
-flushed on every store (partials for stored rounds are dead weight)."""
+flushed on every store (partials for stored rounds are dead weight).
+
+Spans (`drand_tpu.metrics`, one each per recovered round): `agg.partials`
+(the verifier call, live-lane queue included), `agg.recover`,
+`agg.final_verify` and `agg.append` (the store chain and its callbacks'
+hand-off)."""
 
 import queue
 import threading
 
+from .. import metrics
 from ..common import make_condition
 from typing import Callable, Optional
 
@@ -221,7 +227,8 @@ class ChainStore:
         # same signer index from being verified and used.
         unchecked = [p for p in rc.partials.values() if p not in rc.checked]
         if unchecked:
-            results = self.partial_verifier.verify(msg, unchecked)
+            with metrics.span("agg.partials", round=round_):
+                results = self.partial_verifier.verify(msg, unchecked)
             for p, ok in zip(unchecked, results):
                 if ok:
                     rc.checked[p] = True
@@ -238,19 +245,23 @@ class ChainStore:
 
         pub_poly = self.vault.get_pub()
         try:
-            sig = tbls.recover(scheme, pub_poly, msg, good[:thr],
-                               thr, len(self.group), verify_each=False)
+            with metrics.span("agg.recover", round=round_):
+                sig = tbls.recover(scheme, pub_poly, msg, good[:thr],
+                                   thr, len(self.group), verify_each=False)
         except ValueError:
             return
         pub = self.vault.public_key_bytes()
-        if not scheme.verify_beacon(pub, round_, prev_sig, sig):
+        with metrics.span("agg.final_verify", round=round_):
+            ok = scheme.verify_beacon(pub, round_, prev_sig, sig)
+        if not ok:
             # should be unreachable once partials are verified; drop and wait
             # for more honest partials (chainstore.go:207-218)
             rc.partials.clear()
             rc.checked.clear()
             return
         beacon = Beacon(round=round_, signature=sig, previous_sig=prev_sig)
-        self._try_append(last, beacon)
+        with metrics.span("agg.append", round=round_):
+            self._try_append(last, beacon)
 
     def _try_append(self, last: Beacon, beacon: Beacon) -> None:
         if last.round + 1 < beacon.round:

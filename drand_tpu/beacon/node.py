@@ -14,6 +14,7 @@ threshold time (the TPU-first redesign of node.go:150's per-packet pairing).
 
 import threading
 
+from .. import metrics
 from ..common import make_lock
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional
@@ -286,7 +287,8 @@ class Handler:
         prev = last.signature if self.scheme.chained else None
         msg = self.scheme.digest_beacon(round_, prev)
         try:
-            partial = self.vault.sign_partial(msg)
+            with metrics.span("node.sign_partial", round=round_):
+                partial = self.vault.sign_partial(msg)
         except RuntimeError:
             return  # no share yet (waiting on DKG)
         packet = PartialBeaconPacket(

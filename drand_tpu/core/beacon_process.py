@@ -53,6 +53,18 @@ DKG_STATUS_NAMES = {DKG_NOT_STARTED: "not_started", DKG_WAITING: "waiting",
                     DKG_FAILED: "failed"}
 
 
+def aggregation_verifier_factory(verify_svc, use_device: bool):
+    """The aggregator's partial-verifier factory (`HandlerConfig.
+    verifier_factory`) as a daemon builds it: the device verifier (or the
+    host one) on `verify_svc`'s LIVE lane.  Device partial verification
+    falls back to the host verifier when the service's failure domain
+    abandons a device call — live aggregation must survive accelerator
+    loss mid-round."""
+    return verify_svc.partials_factory(
+        device_verifier_factory if use_device else _host_verifier_factory,
+        fallback_factory=_host_verifier_factory if use_device else None)
+
+
 class BeaconProcess:
     def __init__(self, cfg: Config, file_store: FileStore, beacon_id: str,
                  pair: Pair, client: ProtocolClient, log: Logger):
@@ -403,14 +415,8 @@ class BeaconProcess:
             # sync plane / integrity scans below share the BACKGROUND lane
             # of the same service
             verify_svc = self.cfg.verify_service()
-            # device partial verification falls back to the host factory
-            # when the service's failure domain abandons a device call —
-            # live aggregation must survive accelerator loss mid-round
-            verifier_factory = verify_svc.partials_factory(
-                device_verifier_factory if self.cfg.use_device_verifier
-                else _host_verifier_factory,
-                fallback_factory=(_host_verifier_factory
-                                  if self.cfg.use_device_verifier else None))
+            verifier_factory = aggregation_verifier_factory(
+                verify_svc, self.cfg.use_device_verifier)
             self.monitor = ThresholdMonitor(self.beacon_id, self.log,
                                             self.group.threshold)
             self.monitor.start()

@@ -1,26 +1,39 @@
-"""Batched threshold-partial verification on TPU (BASELINE config 3).
+"""Batched threshold-partial verification on the device.
 
 The reference verifies each incoming partial with two pairings on the CPU
 (`tbls.VerifyPartial`, chain/beacon/node.go:150) — O(n) pairings per round
 per node, its hottest call site.  Here a whole (rounds x slots) block is
-collapsed into ONE Miller product via a per-signer random linear combination:
+collapsed into ONE Miller product via a per-slot random linear combination:
 
     forall (r,j):  e(-g1, S_rj) · e(pk_idx(rj), H_r) == 1
     ==>  e(-g1, sum_rj c_rj·S_rj) · prod_i e(pk_i, T_i) == 1
          with  T_i = sum over slots with idx==i of c_rj·H_r
 
 sound except with probability ~2^-SECURITY_BITS.  pk_i = PubPoly.eval(i) is
-evaluated once per group on the host (the polynomial is tiny); the Miller
-product has (#distinct signers + 1) pairs.  On RLC failure, exact per-slot
-pairing checks locate invalid partials.
+evaluated once per group on the host (the polynomial is tiny).
 
-Occupancy fast path (ISSUE 10, ported from the r4 G1/G2 verify machinery):
+Fixed shapes: a group of n nodes dispatches ONE program per front and
+round count, whatever arrives.  Each round's slot axis is padded to n (a
+multiple of n for a longer row), the signer axis is all n nodes, and a
+signer with no live slot gives T_i = infinity, whose pair is made inert
+(P = (0, 0) with a finite Q: the Miller value then lies in Fp2, which the
+final exponentiation maps to 1).  So a round that verifies 1, 6 or 10
+partials, from any signer subset, reuses the first call.
 
-  * the host no longer decompresses partials point by point — wire bytes
+A failing block is localised with the SAME program: slots whose point
+failed decompression or the subgroup check are dropped at once, and the
+rest is bisected by re-running the check over halves of the slot mask.
+A one-slot mask with a nonzero coefficient is an exact check of that
+slot; a passing half next to a failing whole convicts the other half
+without a pass of its own.
+
+Occupancy fast path (ported from the G1/G2 verify machinery):
+
+  * the host does not decompress partials point by point — wire bytes
     are split into x-limb arrays with pure numpy (`batch._wire_parse`) and
     the y recovery rides the SAME single sqrt_ratio pow scan as the two
     SSWU hash maps (`ops/h2c.g2_decompress_and_hash`; scans cost per
-    step, not per lane — the G1/G2 free lunch, now on partials);
+    step, not per lane);
   * the RLC MSM uses the split-sampled GLV coefficients: ψ-split 4-way on
     G2 (32-step joint ladder) and φ-split 2-way on G1 (64-step), exactly
     like crypto/batch.py's verify pipelines, instead of a 128-step
@@ -28,7 +41,7 @@ Occupancy fast path (ISSUE 10, ported from the r4 G1/G2 verify machinery):
     directly in split form (injective; see batch._device_rlc_bits).
 
 Slot layout: callers pass ragged per-round partial lists (wire format:
-be16(index) || sig); rows are padded to the widest row and masked.
+be16(index) || sig); rows are padded to the slot width and masked.
 """
 
 from functools import lru_cache
@@ -37,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import metrics
 from . import tbls as HT
 from .batch import (_NEG_G1, _NEG_G2, _device_rlc_bits, run_program,
                     _gen_sub, _rlc_keys, _wire_parse, _GEN_JAC_G1,
@@ -84,10 +98,10 @@ def _prepend_point(single, stacked):
 
 
 def _partials_verdict(sub_ok, ok, valid):
-    """Fused device scalar: RLC ok AND every valid slot's decompression +
-    subgroup check ok (a slot that failed device decompression has a
+    """Fused device scalar: RLC ok AND every masked-in slot's decompression
+    + subgroup check ok (a slot that failed device decompression has a
     generator substitute and a live coefficient, so the RLC itself also
-    fails — the fallback then localizes it)."""
+    fails — localisation then drops it by its `sub_ok`)."""
     return ok & jnp.all(sub_ok | ~valid.astype(bool))
 
 
@@ -123,9 +137,17 @@ def _rlc_partials_run_g2sig(sig_x, sign, u0, u1, keys, valid, onehot,
     ch = jax.tree.map(lambda t: t[2 * rk:], mult)
     onehot2 = jnp.concatenate([onehot, onehot], axis=1)
     ts = _masked_sums(DC.G2_DEV, ch, onehot2)
-    qx_all, qy_all, _ = DC.G2_DEV.to_affine(_prepend_point(s_sum, ts))
+    qx_all, qy_all, q_inf = DC.G2_DEV.to_affine(_prepend_point(s_sum, ts))
     px = jnp.concatenate([neg_g1_aff[0][None], pk_sel[0]], axis=0)
     py = jnp.concatenate([neg_g1_aff[1][None], pk_sel[1]], axis=0)
+    # e(P, infinity) = 1, but the Miller loop needs a finite Q: such a
+    # pair (a signer with no live slot) becomes P = (0, 0) against the
+    # generator, whose Miller value lies in Fp2 and exponentiates to 1
+    px = L.select(q_inf, jnp.zeros_like(px), px)
+    py = L.select(q_inf, jnp.zeros_like(py), py)
+    qx_all, qy_all = (jax.tree.map(
+        lambda q, g: L.select(q_inf, jnp.broadcast_to(g, q.shape), q), qc, gc)
+        for qc, gc in ((qx_all, _GEN_JAC_G2[0]), (qy_all, _GEN_JAC_G2[1])))
     ok = DP.paired_product_is_one(px, py, (qx_all, qy_all),
                                   onehot.shape[0] + 1)
     return sub_ok, _partials_verdict(sub_ok, ok, valid)
@@ -134,7 +156,9 @@ def _rlc_partials_run_g2sig(sig_x, sign, u0, u1, keys, valid, onehot,
 def _rlc_partials_run_g1sig(sig_x, sign, u0, u1, keys, valid, onehot,
                             pk_sel, neg_g2_aff):
     """sigs on G1, pks on G2 (short-sig scheme): fused decompression via
-    the shared (p-3)/4 scan + φ-split 2-way GLV (64-step joint ladder)."""
+    the shared (p-3)/4 scan + φ-split 2-way GLV (64-step joint ladder).
+    A signer with no live slot has T_i = infinity, whose affine form
+    (0, 0) is already an inert P against its finite key."""
     rk = onehot.shape[1]
     r = u0.shape[0]
     k = rk // r
@@ -160,46 +184,6 @@ def _rlc_partials_run_g1sig(sig_x, sign, u0, u1, keys, valid, onehot,
     return sub_ok, _partials_verdict(sub_ok, ok, valid)
 
 
-def _exact_partials_run_g2sig(sig_x, sign, u0, u1, pk_slot, neg_g1_aff):
-    """Per-slot exact checks with per-slot pubkeys (fallback path); the
-    decompression rides the same fused front end as the RLC pass."""
-    rk = sig_x[0].shape[0]
-    r = u0[0].shape[0]
-    k = rk // r
-    sig_jac, parse_ok, hm_r = DH.g2_decompress_and_hash(
-        sig_x[0], sig_x[1], sign, u0, u1)
-    sig_jac = _gen_sub(DC.G2_DEV, _GEN_JAC_G2, sig_jac, parse_ok)
-    sub_ok = DC.g2_in_subgroup(sig_jac) & parse_ok
-    hm = _tile_rounds(hm_r, k)
-    sx, sy, s_inf = DC.G2_DEV.to_affine(sig_jac)
-    hx, hy, _ = DC.G2_DEV.to_affine(hm)
-    px = jnp.stack([jnp.broadcast_to(neg_g1_aff[0], (rk, L.NLIMB)), pk_slot[0]])
-    py = jnp.stack([jnp.broadcast_to(neg_g1_aff[1], (rk, L.NLIMB)), pk_slot[1]])
-    qx = jax.tree.map(lambda a, b: jnp.stack([a, b]), sx, hx)
-    qy = jax.tree.map(lambda a, b: jnp.stack([a, b]), sy, hy)
-    ok = DP.paired_product_is_one(px, py, (qx, qy), 2)
-    return sub_ok & ~s_inf & ok
-
-
-def _exact_partials_run_g1sig(sig_x, sign, u0, u1, pk_slot, neg_g2_aff):
-    rk = sig_x.shape[0]
-    r = u0.shape[0]
-    k = rk // r
-    sig_jac, parse_ok, hm_r = DH.g1_decompress_and_hash(sig_x, sign, u0, u1)
-    sig_jac = _gen_sub(DC.G1_DEV, _GEN_JAC_G1, sig_jac, parse_ok)
-    sub_ok = DC.g1_in_subgroup(sig_jac) & parse_ok
-    hm = _tile_rounds(hm_r, k)
-    sx, sy, s_inf = DC.G1_DEV.to_affine(sig_jac)
-    hx, hy, _ = DC.G1_DEV.to_affine(hm)
-    px = jnp.stack([sx, hx])
-    py = jnp.stack([sy, hy])
-    bc = lambda c: jnp.broadcast_to(c, (rk, L.NLIMB))
-    qx = jax.tree.map(lambda a, b: jnp.stack([bc(a), b]), neg_g2_aff[0], pk_slot[0])
-    qy = jax.tree.map(lambda a, b: jnp.stack([bc(a), b]), neg_g2_aff[1], pk_slot[1])
-    ok = DP.paired_product_is_one(px, py, (qx, qy), 2)
-    return sub_ok & ~s_inf & ok
-
-
 @lru_cache(maxsize=None)
 def _rlc_pipeline(g2sig: bool, front: str = FRONT_FIELDS, dst: bytes = b""):
     # front resolver shared with the beacon pipelines (batch._h2f_front):
@@ -213,19 +197,6 @@ def _rlc_pipeline(g2sig: bool, front: str = FRONT_FIELDS, dst: bytes = b""):
         u0, u1 = h2f(msg)
         return core(sig_x, sign, u0, u1, keys, valid, onehot, pk_sel,
                     fixed_aff)
-
-    return jax.jit(run)
-
-
-@lru_cache(maxsize=None)
-def _exact_pipeline(g2sig: bool, front: str = FRONT_FIELDS,
-                    dst: bytes = b""):
-    core = _exact_partials_run_g2sig if g2sig else _exact_partials_run_g1sig
-    h2f = _h2f_front(g2sig, front, dst)
-
-    def run(sig_x, sign, msg, pk_slot, fixed_aff):
-        u0, u1 = h2f(msg)
-        return core(sig_x, sign, u0, u1, pk_slot, fixed_aff)
 
     return jax.jit(run)
 
@@ -267,8 +238,7 @@ class BatchPartialVerifier:
         runs on device inside the fused pipelines).  Host-detectable
         badness (missing slot, wrong length, bad flags, x >= p, signer
         index out of range) lands in the valid mask; slots whose x has no
-        y on the curve are caught by the device parse_ok and localized by
-        the exact fallback."""
+        y on the curve are caught by the device parse_ok (`sub_ok`)."""
         nb = 96 if self.g2sig else 48
         sig_bytes, idxs, idx_ok = [], [], []
         for row in rows:
@@ -330,41 +300,75 @@ class BatchPartialVerifier:
     def verify_partials(self, msgs, partial_rows) -> np.ndarray:
         """msgs: one digest per round; partial_rows: ragged per-round lists of
         wire partials (be16(index) || sig).  Returns an (r, kmax) validity
-        mask (padded slots are False)."""
+        mask (padded slots are False).  Each round's slots are padded to
+        the group size, so the program's shape depends on the round count
+        alone."""
         r = len(msgs)
         if r == 0:
             return np.zeros((0, 0), dtype=bool)
-        k = max((len(row) for row in partial_rows), default=0)
-        if k == 0:
+        kmax = max((len(row) for row in partial_rows), default=0)
+        if kmax == 0:
             return np.zeros((r, 0), dtype=bool)
+        k = -(-kmax // self.n_nodes) * self.n_nodes
         xw, sign, idxs, valid = self._parse(partial_rows, k)
-        if not valid.any():
-            return valid  # nothing parsed — no device work to do
-        sig_x = self._sig_x(xw)
-        sign_d = jnp.asarray(sign)
+        good = np.zeros(r * k, dtype=bool)
+        ids = np.flatnonzero(valid.reshape(-1))
+        if ids.size:
+            check = self._checker(msgs, xw, sign, idxs.reshape(-1))
+            ok, sub_ok = check(ids)
+            if ok:
+                good[ids] = True
+            else:
+                live = ids[sub_ok[ids]]
+                _localise(check, live, live.size == ids.size, good)
+        out = good.reshape(r, k)[:, :kmax]
+        bad = sum(len(row) for row in partial_rows) - int(out.sum())
+        if bad:
+            metrics.add("partials.invalid", count=bad)
+        return out
+
+    def _checker(self, msgs, xw, sign, flat_idx):
+        """-> check(slot ids) -> (RLC verdict over those slots, per-slot
+        decompression + subgroup flags): one device pass of the block's
+        program, with fresh coefficients, over the given slots."""
         front, msg = self._msg_enc(msgs)
+        pipe = _rlc_pipeline(self.g2sig, front, self.scheme.dst)
+        name = f"{'g2' if self.g2sig else 'g1'}_partials_rlc.{front}"
+        # every node is a signer row; a masked-out slot's coefficient is 0
+        onehot = jnp.asarray((flat_idx[None, :] == np.arange(
+            self.n_nodes)[:, None]).astype(np.uint32))
+        block = (self._sig_x(xw), jnp.asarray(sign), msg)
+        pk_all = self._pk_sel(np.arange(self.n_nodes))
 
-        flat_valid = valid.reshape(-1)
-        flat_idx = idxs.reshape(-1)
-        signers = sorted(set(flat_idx[flat_valid]))
-        onehot = np.zeros((len(signers), r * k), dtype=np.uint32)
-        for i, s in enumerate(signers):
-            onehot[i] = (flat_idx == s) & flat_valid
-        # per-slot randomizers are sampled on device from a fresh 128-bit
-        # key (batch._device_rlc_bits); invalid slots get zero coefficients
-        _, all_ok = run_program(
-            _rlc_pipeline(self.g2sig, front, self.scheme.dst),
-            sig_x, sign_d, msg, jnp.asarray(_rlc_keys()),
-            jnp.asarray(flat_valid.astype(np.uint32)), jnp.asarray(onehot),
-            self._pk_sel(signers), self.fixed_aff,
-            name=f"{'g2' if self.g2sig else 'g1'}_partials_rlc.{front}")
-        if bool(all_ok):
-            return valid
+        def check(ids):
+            mask = np.zeros(flat_idx.size, dtype=np.uint32)
+            mask[ids] = 1
+            metrics.add("partials.pass")
+            sub_ok, ok = run_program(
+                pipe, *block, jnp.asarray(_rlc_keys()), jnp.asarray(mask),
+                onehot, pk_all, self.fixed_aff, name=name)
+            return bool(ok), np.asarray(sub_ok)
 
-        # exact fallback: per-slot pairings with per-slot public shares
-        pk_slot = self._pk_sel(idxs.reshape(-1))
-        got = np.asarray(run_program(
-            _exact_pipeline(self.g2sig, front, self.scheme.dst),
-            sig_x, sign_d, msg, pk_slot, self.fixed_aff,
-            name=f"{'g2' if self.g2sig else 'g1'}_partials_exact.{front}"))
-        return got.reshape(r, k) & valid
+        return check
+
+
+def _localise(check, ids, failing: bool, good: np.ndarray) -> None:
+    """Set `good` for the valid slots among `ids` by halves of the mask.
+    `failing`: the check over `ids` is known to fail.  A valid slot never
+    fails a check, so a failing whole whose first half passes has an
+    invalid slot in its second half."""
+    if ids.size == 0:
+        return
+    if not failing:
+        if check(ids)[0]:
+            good[ids] = True
+            return
+    if ids.size == 1:
+        return
+    lo, hi = ids[:ids.size // 2], ids[ids.size // 2:]
+    if check(lo)[0]:
+        good[lo] = True
+        _localise(check, hi, True, good)
+    else:
+        _localise(check, lo, True, good)
+        _localise(check, hi, False, good)

@@ -4,8 +4,11 @@ Wire format parity with kyber/sign/tbls (SURVEY.md §2.9): a partial signature
 is `be16(share_index) || bls_signature`.  Share index i corresponds to
 polynomial evaluation at x = i + 1.
 
-The batched device equivalents (vmapped partial verification, Lagrange
-recovery in the exponent) live in drand_tpu.crypto.jax.tbls.
+The batched device equivalents live in drand_tpu.crypto.partials
+(`BatchPartialVerifier`: a block of partials in one random-linear-
+combination check) and drand_tpu.crypto.batch (`recover_batch`: Lagrange
+recovery in the exponent for many rounds at once).  The daemon's
+aggregator recovers with `recover` below.
 """
 
 import secrets

@@ -377,7 +377,8 @@ class _PartialLaneVerifier:
     background scans at the next chunk boundary instead of contending
     for the device ad hoc.  When a fallback factory is provided, a
     device failure (watchdog abandon or repeated raise) falls back to
-    the host partial verifier instead of costing the round."""
+    the host partial verifier instead of costing the round, and counts
+    `partials.fallback`."""
 
     def __init__(self, service: "VerifyService", inner,
                  fallback_factory: Optional[Callable] = None):
@@ -397,6 +398,7 @@ class _PartialLaneVerifier:
         except Exception:
             if self._fallback_factory is None:
                 raise
+            metrics.add("partials.fallback")
             if self._fallback is None:
                 self._fallback = self._fallback_factory()
             fb = self._fallback

@@ -105,6 +105,7 @@ else:
 # future tests/test_batching.py must not silently vanish from the gate).
 _HEAVY_FILES = {"test_batch", "test_batch_sign", "test_multichip",
                 "test_ops_curve_pairing", "test_partials",
+                "test_aggregate_reference",
                 "test_ops_pallas", "test_ops_pallas_pairing"}
 # the one integrity test that runs the DEVICE verifier: ordered into the
 # heavy bucket (after test_batch, which compiles the same pad-8 RLC

@@ -1,6 +1,6 @@
 """Batched threshold-partial verification (drand_tpu/crypto/partials.py)
-against the host tbls golden path.  Shapes stay tiny (r=2, k=2) so each
-orientation compiles one RLC and one exact program.
+against the host tbls golden path.  Groups stay tiny (n=3), so each
+orientation compiles one program per round count.
 """
 
 import numpy as np
@@ -32,7 +32,7 @@ def test_verify_partials_happy_and_fallback(scheme_id):
         for p in row:
             assert tbls.verify_partial(sch, pp, m, p)
 
-    # corruption is localized by the exact fallback
+    # corruption is localised by halves of the slot mask
     bad = bytearray(rows[1][0])
     bad[10] ^= 1
     rows2 = [rows[0], [bytes(bad), rows[1][1]]]
@@ -61,7 +61,7 @@ def test_verify_partials_empty():
 def test_verify_partials_non_decompressable_slot_localized(scheme_id):
     """ISSUE 10: the fast path decompresses ON DEVICE (the fused
     sqrt_ratio front end), so an x with no y on the curve is caught by
-    the device parse_ok and localized by the exact fallback — matching
+    the device parse_ok and dropped by that per-slot flag — matching
     the host golden decoder slot for slot."""
     sch, shares, pp, bv = _setup(scheme_id)
     msgs = [sch.digest_beacon(r, None) for r in (1, 2)]
@@ -85,3 +85,26 @@ def test_verify_partials_non_decompressable_slot_localized(scheme_id):
     assert got.tolist() == [[True, False], [True, True]]
     # host golden agrees the tweaked partial is invalid
     assert not tbls.verify_partial(sch, pp, msgs[0], bytes(cand))
+
+
+@pytest.mark.parametrize("scheme_id", ["bls-unchained-on-g1",
+                                       "pedersen-bls-chained"])
+def test_one_program_for_any_slot_count_and_signer_subset(scheme_id):
+    """A round's slots are padded to the group size and every node is a
+    signer row: 1 slot, n slots and a subset of others (with one invalid
+    partial, localised by halves) all run the first call's program."""
+    from drand_tpu import metrics
+    n = 3
+    sch, shares, pp, bv = _setup(scheme_id, t=2, n=n)
+    prev = bytes(96) if sch.chained else None
+    msg = sch.digest_beacon(9, prev)
+    parts = [tbls.sign_partial(sch, s, msg) for s in shares]
+    wrong = tbls.sign_partial(sch, shares[1], sch.digest_beacon(8, prev))
+    g = "g2" if sch.sig_group.point_len == 96 else "g1"
+    flavour = f"batch.first_call/{g}_partials_rlc.fields@{n}"
+    assert bv.verify_partials([msg], [parts[2:]]).tolist() == [[True]]
+    assert bv.verify_partials([msg], [parts]).tolist() == [[True] * n]
+    assert bv.verify_partials([msg], [[parts[2], wrong]]).tolist() \
+        == [[True, False]]
+    assert bv.verify_partials([msg], [[wrong]]).tolist() == [[False]]
+    assert metrics.totals()[flavour][0] == 1

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from drand_tpu import metrics
 from drand_tpu.beacon.clock import FakeClock
 from drand_tpu.crypto.verify_service import (LANE_BACKGROUND, LANE_LIVE,
                                              VerifyService, current_service,
@@ -918,8 +919,11 @@ def test_partials_fall_back_to_host_factory_on_device_failure():
 
     pv = svc.partials_factory(dev_factory, fallback_factory=host_factory)(
         SCHEME, None, 3)
+    fell = metrics.totals().get("partials.fallback", [0])[0]
     assert pv.verify(b"m", [b"p1", b"p2"]) == [True, True]
     assert calls["dev"] == 2 and calls["host"] == 1
+    # the fallback is counted where a check can read it
+    assert metrics.totals()["partials.fallback"][0] == fell + 1
     svc.stop()
 
 
