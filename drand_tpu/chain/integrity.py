@@ -27,9 +27,12 @@ offline).
 
 import hashlib
 import json
+import threading
+import time
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, Generator, List, Optional, Sequence
 
+from ..common import make_condition
 from .beacon import Beacon
 from .errors import ErrNoBeaconSaved, ErrNoBeaconStored
 
@@ -201,9 +204,14 @@ class IntegrityScanner:
         row is re-read and its signature hash must match, else the scan
         silently falls back to a full walk (`report.resumed_from` says
         which happened).  Every scan emits a fresh `report.checkpoint`
-        advancing the watermark over the rounds that scanned clean."""
+        advancing the watermark over the rounds that scanned clean.
+
+        A full scan reads the store one chunk ahead of the verifier, on a
+        thread that is joined before this returns or raises; the report,
+        the progress calls and the counters are those of a walk that
+        reads each chunk after the previous one's verdict."""
         from ..metrics import (integrity_beacons_scanned,
-                               integrity_corrupt_found, span)
+                               integrity_corrupt_found)
         if mode not in (MODE_LINKAGE, MODE_FULL):
             raise ValueError(f"unknown scan mode {mode!r}")
         if mode == MODE_FULL and self.verifier is None:
@@ -239,59 +247,76 @@ class IntegrityScanner:
                 digest = resume.digest
                 start_round = resume.round + 1
                 report.resumed_from = resume.round
-        buf: List[Beacon] = []
-        buf_prevs: List[Optional[bytes]] = []
+        records = self._walk(start_round, report.upto, prev_round, prev_sig,
+                             digest, sig_len)
+        if mode == MODE_FULL:
+            # chunk k+1 is read while the verifier works on chunk k
+            records = _ReadAhead(records)
         unverified = set()      # rounds whose signature never reached verify
-        unflushed = 0           # rounds examined since the last flush —
-                                # counts malformed/unlinked rows too, which
-                                # never enter the verify buffer
+        try:
+            for c in records:
+                report.findings.extend(c.findings)
+                report.scanned += c.examined
+                unverified.update(c.unverified)
+                self._verify_chunk(report, c.beacons, c.prevs, mode)
+                if c.examined:
+                    integrity_beacons_scanned.labels(
+                        self.beacon_id, vfy_kind, self.trigger).inc(c.examined)
+                # watermark: commit only while the scan is STILL clean — the
+                # first finding freezes the checkpoint at the previous
+                # chunk, so the next resume re-examines everything from
+                # there on
+                if not report.findings and c.last_round >= 1 \
+                        and c.last_sig is not None:
+                    report.checkpoint = ScanCheckpoint(
+                        c.last_round, c.digest,
+                        hashlib.sha256(c.last_sig).hexdigest(), mode)
+                if progress is not None:
+                    progress(c.done, report.upto)
+        finally:
+            records.close()
 
-        def flush(done_round: int) -> None:
-            nonlocal unflushed
-            if buf:
-                self._verify_chunk(report, buf, buf_prevs, mode)
-                buf.clear()
-                buf_prevs.clear()
-            if unflushed:
-                integrity_beacons_scanned.labels(
-                    self.beacon_id, vfy_kind, self.trigger).inc(unflushed)
-                unflushed = 0
-            # watermark: commit only while the scan is STILL clean — the
-            # first finding freezes the checkpoint at the previous flush,
-            # so the next resume re-examines everything from there on
-            if not report.findings and prev_round >= 1 \
-                    and prev_sig is not None:
-                report.checkpoint = ScanCheckpoint(
-                    prev_round, digest,
-                    hashlib.sha256(prev_sig).hexdigest(), mode)
-            if progress is not None:
-                progress(done_round, report.upto)
+        self._reclassify_corrupt_anchors(report, unverified)
+        for f in report.findings:
+            integrity_corrupt_found.labels(self.beacon_id, f.kind,
+                                           self.trigger).inc()
+        report.findings.sort(key=lambda f: (f.round, f.kind))
+        return report
 
-        # `integrity.read`: the rows between two flushes (cursor reads,
-        # linkage, digest), one span per chunk; the flush's verify is
-        # the verify service's own spans
+    def _walk(self, start_round: int, upto: int, prev_round: int,
+              prev_sig: Optional[bytes], digest: str,
+              sig_len: int) -> Generator["_Chunk", None, None]:
+        """Read rounds start_round..upto through a cursor of its own,
+        opened on the first `next()`: one `_Chunk` per `self.chunk` rows
+        to verify, then a last one with the rows left over and the
+        MISSING rounds of the tail.  `prev_round`, `prev_sig` and
+        `digest` are the walk's state below `start_round`."""
+        from ..metrics import span
+        c = _Chunk()
+        # `integrity.read`: the rows of one chunk (cursor reads, linkage,
+        # digest), one span per chunk; its verify is the verify service's
+        # own spans
         cur = self.store.cursor()
         b = _cursor_seek(cur, start_round)
-        while b is not None and b.round <= report.upto:
+        while b is not None and b.round <= upto:
             with span("integrity.read", round=b.round):
-                while b is not None and b.round <= report.upto:
+                while b is not None and b.round <= upto:
                     r = b.round
                     if r > prev_round + 1:
                         for gap in range(prev_round + 1, r):
-                            report.findings.append(Finding(gap, MISSING))
+                            c.findings.append(Finding(gap, MISSING))
                         # the walk anchor is lost across a hole; fall back
                         # to the store's own previous_sig below when it
                         # has one
                         prev_sig = None
-                    report.scanned += 1
-                    unflushed += 1
+                    c.examined += 1
                     sig = b.signature
                     well_formed = len(sig) == sig_len
                     if not well_formed:
                         # torn write: the row exists but is not a point
                         # encoding
-                        unverified.add(r)
-                        report.findings.append(Finding(
+                        c.unverified.append(r)
+                        c.findings.append(Finding(
                             r, MALFORMED,
                             f"signature is {len(sig)} bytes, want {sig_len}"))
                     elif self.scheme.chained:
@@ -299,7 +324,7 @@ class IntegrityScanner:
                                 and prev_sig is not None \
                                 and r == prev_round + 1 \
                                 and b.previous_sig != prev_sig:
-                            report.findings.append(Finding(
+                            c.findings.append(Finding(
                                 r, UNLINKED,
                                 "stored previous_sig does not match round "
                                 f"{r - 1}'s stored signature"))
@@ -310,37 +335,31 @@ class IntegrityScanner:
                             # cannot be rebuilt, so the round cannot be
                             # proven valid — flag it for re-fetch rather
                             # than vouch for it blindly
-                            unverified.add(r)
-                            report.findings.append(Finding(
+                            c.unverified.append(r)
+                            c.findings.append(Finding(
                                 r, UNLINKED,
                                 "previous signature unavailable (hole below)"))
                         else:
-                            buf.append(b)
-                            buf_prevs.append(use_prev)
+                            c.beacons.append(b)
+                            c.prevs.append(use_prev)
                     else:
-                        buf.append(b)
-                        buf_prevs.append(None)
+                        c.beacons.append(b)
+                        c.prevs.append(None)
                     # a torn row can't anchor the next round's linkage
                     prev_sig = sig if well_formed else None
                     prev_round = r
                     if well_formed:
                         digest = _roll_digest(digest, r, sig)
-                    if len(buf) >= self.chunk:
+                    if len(c.beacons) >= self.chunk:
                         break
                     b = cur.next()
-            if len(buf) >= self.chunk:
-                flush(prev_round)
+            if len(c.beacons) >= self.chunk:
+                yield c.ending(prev_round, prev_round, digest, prev_sig)
+                c = _Chunk()
                 b = cur.next()
-        for gap in range(prev_round + 1, report.upto + 1):
-            report.findings.append(Finding(gap, MISSING))
-        flush(report.upto)
-
-        self._reclassify_corrupt_anchors(report, unverified)
-        for f in report.findings:
-            integrity_corrupt_found.labels(self.beacon_id, f.kind,
-                                           self.trigger).inc()
-        report.findings.sort(key=lambda f: (f.round, f.kind))
-        return report
+        for gap in range(prev_round + 1, upto + 1):
+            c.findings.append(Finding(gap, MISSING))
+        yield c.ending(upto, prev_round, digest, prev_sig)
 
     def _reclassify_corrupt_anchors(self, report: ScanReport,
                                     unverified: set) -> None:
@@ -447,6 +466,130 @@ class IntegrityScanner:
         if deleted:
             integrity_quarantined.labels(self.beacon_id).inc(len(deleted))
         return deleted
+
+
+@dataclass
+class _Chunk:
+    """What the walk read for one flush: the rows to verify with their
+    previous signatures, the findings met reading them, and the walk's
+    state after its last row (the watermark the flush may commit)."""
+
+    beacons: List[Beacon] = field(default_factory=list)
+    prevs: List[Optional[bytes]] = field(default_factory=list)
+    findings: List[Finding] = field(default_factory=list)
+    unverified: List[int] = field(default_factory=list)
+    examined: int = 0       # rows read: malformed and unlinked ones too,
+                            # which never reach the verifier
+    done: int = 0           # the round its `progress` call reports
+    last_round: int = 0
+    digest: str = _DIGEST_SEED
+    last_sig: Optional[bytes] = None
+
+    def ending(self, done: int, last_round: int, digest: str,
+               last_sig: Optional[bytes]) -> "_Chunk":
+        self.done, self.last_round = done, last_round
+        self.digest, self.last_sig = digest, last_sig
+        return self
+
+
+class _ReadAhead:
+    """Iterate `records` one record ahead of the caller, on a daemon
+    thread of its own: the thread reads the next record while the caller
+    works on the one it took, and reads no further until the caller takes
+    that one, so at most two are alive.  An exception raised reading is
+    re-raised on the caller after every record read before it.  The
+    caller's time blocked in `next()` is the span `integrity.wait`, one
+    count per record taken.  `close()` stops and joins the thread.
+
+    Each read after the first two is centred in the caller's expected
+    work on the record it took (its last record's time, less the last
+    read's, halved, is the wait before the read): the verifier's own
+    host work at the start and end of a verify (packing, dispatch, the
+    verdict's hand-back; serial hops between its threads) then does not
+    have to win the interpreter lock from the read.  A caller that asks
+    for the next record sooner ends that wait at once."""
+
+    def __init__(self, records: Generator):
+        self._records = records
+        self._cv = make_condition(name="integrity-read-ahead")
+        self._next = None           # a record handed over, not yet taken
+        self._ended = False         # the thread has stopped reading
+        self._error: Optional[BaseException] = None
+        self._closing = False
+        self._taken_at: Optional[float] = None
+        self._busy: Optional[float] = None  # caller's time on its last record
+        self._asking = False        # the caller is blocked in next()
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="integrity-read-ahead")
+        self._thread.start()
+
+    def _run(self) -> None:
+        error = None
+        try:
+            read_s = None
+            while True:
+                with self._cv:
+                    if read_s is not None and self._busy is not None:
+                        self._cv.wait_for(
+                            lambda: self._closing or self._asking,
+                            (self._busy - read_s) / 2)
+                    if self._closing:
+                        break
+                t0 = time.perf_counter()
+                rec = next(self._records, None)
+                if rec is None:
+                    break
+                read_s = time.perf_counter() - t0
+                with self._cv:
+                    self._next = rec
+                    self._cv.notify_all()
+                    while self._next is not None and not self._closing:
+                        # bounded by the caller: it takes the record or
+                        # closes, and close() sets `_closing`
+                        # tpu-vet: disable=wait
+                        self._cv.wait()
+        except BaseException as e:  # noqa: BLE001 — re-raised on the caller
+            error = e
+        finally:
+            self._records.close()
+            with self._cv:
+                self._error, self._ended = error, True
+                self._cv.notify_all()
+
+    def __iter__(self) -> "_ReadAhead":
+        return self
+
+    def __next__(self):
+        from ..metrics import add
+        t0 = time.perf_counter()
+        with self._cv:
+            if self._taken_at is not None:
+                self._busy = t0 - self._taken_at
+            self._asking = True
+            self._cv.notify_all()
+            while self._next is None and not self._ended:
+                # bounded by the reader: it hands a record over or ends
+                # tpu-vet: disable=wait
+                self._cv.wait()
+            self._asking = False
+            rec, self._next = self._next, None
+            self._taken_at = time.perf_counter()
+            self._cv.notify_all()
+        if rec is None:
+            if self._error is not None:
+                raise self._error
+            raise StopIteration
+        add("integrity.wait", self._taken_at - t0)
+        return rec
+
+    def close(self) -> None:
+        with self._cv:
+            self._closing = True
+            self._cv.notify_all()
+        # bounded by one chunk's read and the wait before it: the thread
+        # stops at the next record it hands over
+        # tpu-vet: disable=wait
+        self._thread.join()
 
 
 def _cursor_seek(cur, round_: int):

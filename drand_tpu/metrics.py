@@ -350,10 +350,11 @@ authz_tokens = Counter(
 
 # -- program spans and counters ---------------------------------------------
 # One in-memory registry of the program's own timing, kept where the work
-# happens (the scanner's store reads, the verify service's pack, dispatch
-# and verdict, a device program's first call): each name holds [count,
-# host seconds].  VerifyService.stats()["spans"] carries a snapshot (the
-# benchmark deltas two of them), and /metrics exports the seconds.
+# happens (the scanner's store reads and its wait for them, the verify
+# service's pack, dispatch and verdict, a device program's first call):
+# each name holds [count, host seconds].  VerifyService.stats()["spans"]
+# carries a snapshot (the benchmark deltas two of them), and /metrics
+# exports the seconds.
 
 span_seconds = Counter(
     "drand_span_seconds_total",

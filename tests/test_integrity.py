@@ -6,10 +6,14 @@ is clean — all with a fake clock and in-memory peers (zero network I/O).
 
 import os
 import sys
+import threading
+import time
 
 import pytest
 
+from drand_tpu.chain import integrity
 from drand_tpu.chain.beacon import Beacon, genesis_beacon
+from drand_tpu.chain.errors import ErrMissingPrevious
 from drand_tpu.chain.integrity import (INVALID_SIG, MALFORMED, MISSING,
                                        UNLINKED, IntegrityScanner)
 from drand_tpu.chain.memdb import MemDBStore
@@ -177,6 +181,358 @@ def test_quarantine_plain_list_skips_absent_rounds(chain):
     assert deleted == [3]
     assert integrity_quarantined.labels(
         "test-quarantine-plain")._value.get() == before + 1
+
+
+# ---------------------------------------------------------------------------
+# the read-ahead: a full scan reads chunk k+1 while chunk k verifies
+# ---------------------------------------------------------------------------
+
+READER = "integrity-read-ahead"
+
+
+def _reader_threads():
+    return [t for t in threading.enumerate()
+            if t.name == READER and t.is_alive()]
+
+
+class _WatchedStore:
+    """The store as the scanner sees it, plus the highest round its
+    cursors have returned, whether one ran out, and the threads that
+    opened them."""
+
+    def __init__(self, store):
+        self._store = store
+        self.cv = threading.Condition()
+        self.seen = 0
+        self.ended = False
+        self.cursor_threads = []
+        self.read_at = {}           # round -> perf_counter when returned
+
+    def last(self):
+        return self._store.last()
+
+    def get(self, round_):
+        return self._store.get(round_)
+
+    def cursor(self):
+        self.cursor_threads.append(threading.current_thread())
+        return _WatchedCursor(self, self._store.cursor())
+
+
+class _WatchedCursor:
+    def __init__(self, store, cur):
+        self._store = store
+        self._cur = cur
+
+    def _saw(self, b):
+        with self._store.cv:
+            if b is None:
+                self._store.ended = True
+            else:
+                self._store.seen = max(self._store.seen, b.round)
+                self._store.read_at.setdefault(b.round, time.perf_counter())
+            self._store.cv.notify_all()
+        return b
+
+    def seek(self, round_):
+        return self._saw(self._cur.seek(round_))
+
+    def next(self):
+        return self._saw(self._cur.next())
+
+
+class _GatedVerifier:
+    """`verify_batch(chunk k)` answers only once the store's cursor has
+    passed chunk k+1's first row (or run out), so a scan gets past it
+    only if the store is read ahead of the verify; `timeouts` counts the
+    calls that gave up waiting.  With `hold`, it then gives the reader
+    that long to run further, and `ahead` keeps the most rows the cursor
+    was past chunk k's last one."""
+
+    def __init__(self, inner, store, fail_at=None, hold=0.0, timeout=10.0):
+        self.inner, self.store = inner, store
+        self.kind = getattr(inner, "kind", "host")
+        self.fail_at, self.hold, self.timeout = fail_at, hold, timeout
+        self.timeouts = self.ahead = 0
+        self.calls = []
+
+    def verify_batch(self, rounds, sigs, prev_sigs=None):
+        self.calls.append(list(rounds))
+        st, last = self.store, max(rounds)
+        with st.cv:
+            if not st.cv.wait_for(lambda: st.ended or st.seen > last,
+                                  timeout=self.timeout):
+                self.timeouts += 1
+            # a reader two chunks ahead would pass chunk k+1's last row
+            st.cv.wait_for(lambda: st.ended or st.seen > last + 8,
+                           timeout=self.hold)
+            self.ahead = max(self.ahead, st.seen - last)
+        if self.fail_at is not None and self.fail_at in rounds:
+            raise RuntimeError("verifier fault")
+        return self.inner.verify_batch(rounds, sigs, prev_sigs)
+
+
+def _flip(store, r, sig=None, previous_sig=None):
+    b = store.get(r)
+    store.delete(r)
+    store.put(Beacon(round=r, signature=b.signature if sig is None else sig,
+                     previous_sig=b.previous_sig if previous_sig is None
+                     else previous_sig))
+
+
+def _corrupt_case(chain, tmp_path, case):
+    """-> (store, scan kwargs) for one corrupt-store case, chunk 8."""
+    if case == "hole_below_trimmed":
+        # sqlite without require_previous keeps (round, signature) only:
+        # the row above a hole has no anchor to verify against
+        store = _seeded_store(chain, SqliteStore(str(tmp_path / "t.db")),
+                              genesis=True)
+        store.delete(6)
+        return store, {}
+    store = _seeded_store(chain)
+    if case == "gap":
+        store.delete(5)
+        store.delete(13)
+    elif case == "malformed":
+        _flip(store, 12, sig=store.get(12).signature[:40])
+    elif case == "unlinked":
+        _flip(store, 10, previous_sig=b"\x13" * 96)
+    elif case == "invalid_sig":
+        sig = bytearray(store.get(14).signature)
+        sig[7] ^= 0x10
+        _flip(store, 14, sig=bytes(sig))
+    elif case == "resume":
+        ck = _scanner(chain, store).scan(mode="full", upto=16).checkpoint
+        sig = bytearray(store.get(21).signature)
+        sig[3] ^= 0x01
+        _flip(store, 21, sig=bytes(sig))
+        return store, {"resume": ck, "upto": N}
+    elif case == "next_chunk_finding":
+        # chunk 2's first row is torn: the reader meets it while chunk 1
+        # verifies, and chunk 1's watermark must still commit
+        _flip(store, 9, sig=store.get(9).signature[:40])
+    return store, {"upto": N}
+
+
+def _observed_scan(chain, store, verifier, tag, **kw):
+    """A full scan's report fields, its progress calls and its
+    chain_integrity_beacons_scanned delta."""
+    from drand_tpu.metrics import integrity_beacons_scanned
+    counter = integrity_beacons_scanned.labels(tag, verifier.kind, "startup")
+    before = counter._value.get()
+    calls = []
+    report = IntegrityScanner(
+        store, chain.scheme, verifier=verifier,
+        genesis_seed=chain.genesis_seed, chunk=8, beacon_id=tag,
+    ).scan(mode="full", progress=lambda d, u: calls.append((d, u)), **kw)
+    return ((report.findings, report.scanned, report.checkpoint,
+             report.resumed_from, report.upto, report.verifier),
+            calls, counter._value.get() - before)
+
+
+@pytest.mark.parametrize("case", ["gap", "malformed", "unlinked",
+                                  "invalid_sig", "hole_below_trimmed",
+                                  "resume", "next_chunk_finding"])
+def test_read_ahead_reports_match_serial_scan(chain, tmp_path, monkeypatch,
+                                              case):
+    """A full scan that reads one chunk ahead reports exactly what the
+    same walk gives run inline, one chunk after the other: findings,
+    counts, watermark, progress calls and the scanned counter.  Every
+    verify call waits until the cursor has passed the next chunk's first
+    row, so the reader is always a chunk ahead when a chunk flushes."""
+    store, kw = _corrupt_case(chain, tmp_path, case)
+    host = HostBatchVerifier(chain.scheme, chain.public)
+    watched = _WatchedStore(store)
+    gated = _GatedVerifier(host, watched)
+    ahead = _observed_scan(chain, watched, gated, f"ahead-{case}", **kw)
+    assert gated.timeouts == 0          # the overlap engaged every chunk
+    assert watched.cursor_threads[0].name == READER
+    assert not _reader_threads()
+    with monkeypatch.context() as m:
+        m.setattr(integrity, "_ReadAhead", lambda records: records)
+        serial = _observed_scan(chain, store, host, f"serial-{case}", **kw)
+    assert ahead == serial
+    (findings, _, checkpoint, resumed_from, _, _), calls, _ = ahead
+    assert findings
+    if case == "next_chunk_finding":
+        assert checkpoint.round == 8
+    if case == "resume":
+        assert resumed_from == 16 and calls[0] == (24, N)
+    store.close()
+
+
+class _Cancelled(BaseException):
+    """An early exit that is no Exception, as a cancelled caller's."""
+
+
+@pytest.mark.parametrize("end", ["completes", "reader_error",
+                                 "verifier_error", "progress_error",
+                                 "linkage"])
+def test_read_ahead_thread_ends_with_the_scan(chain, tmp_path, monkeypatch,
+                                              end):
+    """The reader runs at most one chunk ahead (8 rows here) and never
+    outlives `scan()`: a reader-side error surfaces on the caller after
+    the chunks read before it have been verified and reported; a
+    verifier or progress error stops and joins the reader; a linkage
+    scan reads inline and starts no thread."""
+    built, read_ahead = [], integrity._ReadAhead
+
+    def spy(records):
+        built.append(records)
+        return read_ahead(records)
+
+    monkeypatch.setattr(integrity, "_ReadAhead", spy)
+    store = _seeded_store(chain, SqliteStore(str(tmp_path / "s.db"),
+                                             require_previous=True),
+                          genesis=True)
+    watched = _WatchedStore(store)
+    verifier = _GatedVerifier(HostBatchVerifier(chain.scheme, chain.public),
+                              watched, fail_at=12 if end == "verifier_error"
+                              else None, hold=0.2)
+    calls = []
+
+    def progress(done, upto):
+        calls.append(done)
+        if end == "progress_error":
+            raise _Cancelled()
+
+    scanner = IntegrityScanner(watched, chain.scheme, verifier=verifier,
+                               genesis_seed=chain.genesis_seed, chunk=8)
+    if end == "completes":
+        report = scanner.scan(mode="full", progress=progress)
+        assert report.clean and calls == [8, 16, 24, N]
+    elif end == "reader_error":
+        store.delete(20)        # reading round 21 raises on the reader
+        with pytest.raises(ErrMissingPrevious):
+            scanner.scan(mode="full", progress=progress)
+        assert calls == [8, 16]
+        assert verifier.calls == [list(range(1, 9)), list(range(9, 17))]
+    elif end == "verifier_error":
+        with pytest.raises(RuntimeError, match="verifier fault"):
+            scanner.scan(mode="full", progress=progress)
+        assert calls == [8]
+    elif end == "progress_error":
+        with pytest.raises(_Cancelled):
+            scanner.scan(mode="full", progress=progress)
+        assert calls == [8]
+    else:
+        report = scanner.scan(mode="linkage", progress=progress)
+        assert report.clean and calls == [8, 16, 24, N]
+        assert watched.cursor_threads == [threading.current_thread()]
+        assert built == []
+    if end != "linkage":
+        assert len(built) == 1 and verifier.timeouts == 0
+        assert 0 < verifier.ahead <= 8
+        assert watched.cursor_threads[0].name == READER
+    assert not _reader_threads()
+    store.close()
+
+
+class _SlowVerifier:
+    """Every signature verifies, after the next of `seconds` (the last
+    one from then on) with the interpreter lock released, as a device
+    pass; keeps each call's start and end."""
+
+    kind = "device"
+
+    def __init__(self, *seconds):
+        self.seconds = list(seconds)
+        self.calls = []
+
+    def verify_batch(self, rounds, sigs, prev_sigs=None):
+        t0 = time.perf_counter()
+        time.sleep(self.seconds[min(len(self.calls), len(self.seconds) - 1)])
+        self.calls.append((t0, time.perf_counter()))
+        return [True] * len(rounds)
+
+
+def test_read_ahead_centres_each_read_in_the_verify(chain):
+    """From the third chunk on, the reader starts the next chunk's read
+    about half-way into the caller's slack (its last record's time less
+    the last read's): clear of the verifier's own host work at the start
+    of a verify, and done before the verify ends."""
+    watched = _WatchedStore(_seeded_store(chain))
+    verifier = _SlowVerifier(0.2)
+    report = IntegrityScanner(watched, chain.scheme, verifier=verifier,
+                              genesis_seed=chain.genesis_seed,
+                              chunk=4).scan(mode="full")
+    assert report.clean and len(verifier.calls) == N // 4
+    for k, (t0, t1) in enumerate(verifier.calls[1:-1], start=1):
+        first_row = watched.read_at[4 * (k + 1) + 1]  # chunk k+1's
+        assert t0 + 0.05 < first_row < t1, k
+
+
+def test_read_ahead_wait_ends_when_the_caller_asks(chain):
+    """A verify far shorter than the last one (the chunk after a
+    bisection) does not make the caller sit out the reader's centring
+    wait: asking for the next chunk ends it, so the caller waits no
+    longer than a read."""
+    from drand_tpu import metrics
+    verifier = _SlowVerifier(0.4, 0.4, 0.4, 0.0)
+    before = metrics.totals().get("integrity.wait", [0, 0.0])[1]
+    report = IntegrityScanner(_seeded_store(chain), chain.scheme,
+                              verifier=verifier,
+                              genesis_seed=chain.genesis_seed,
+                              chunk=4).scan(mode="full")
+    assert report.clean and len(verifier.calls) == N // 4
+    # without the early end, the read after the first fast verify waits
+    # about (0.4 - read) / 2 = 0.2 s
+    assert metrics.totals()["integrity.wait"][1] - before < 0.1
+
+
+class _AllValid:
+    """Every signature verifies: the stress test times the hand-off, not
+    the pairing."""
+
+    kind = "host"
+
+    def verify_batch(self, rounds, sigs, prev_sigs=None):
+        return [True] * len(rounds)
+
+
+def test_read_ahead_scans_at_once_under_fast_thread_switching(chain,
+                                                             monkeypatch):
+    """More scans at once than cores, each with its reader, switching
+    threads every 10 us: every report is the serial walk's, and no reader
+    is left behind."""
+    store = _seeded_store(chain)
+    store.delete(5)
+    _flip(store, 12, sig=store.get(12).signature[:40])
+
+    def scan():
+        return IntegrityScanner(store, chain.scheme, verifier=_AllValid(),
+                                genesis_seed=chain.genesis_seed,
+                                chunk=3).scan(mode="full", upto=N)
+
+    with monkeypatch.context() as m:
+        m.setattr(integrity, "_ReadAhead", lambda records: records)
+        want = scan()
+    got, errors = [], []
+
+    def scans():
+        try:
+            for _ in range(10):
+                got.append(scan())
+        except Exception as e:      # reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=scans, name=f"stress-{i}")
+               for i in range(2 * (os.cpu_count() or 4))]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert len(got) == 10 * len(threads)
+    assert all(r == want for r in got)
+    assert want.rounds(MISSING) == [5] and want.rounds(MALFORMED) == [12]
+    assert not _reader_threads()
 
 
 # ---------------------------------------------------------------------------

@@ -26,8 +26,8 @@ from drand_tpu.crypto.verify_service import VerifyService
 from harness import OwnWork
 
 SCHEME_ID = "bls-unchained-g1-rfc9380"
-SPANS = ("integrity.read", "verify.queue", "verify.pack", "verify.dispatch",
-         "verify.wait", "verify.return")
+SPANS = ("integrity.read", "integrity.wait", "verify.queue", "verify.pack",
+         "verify.dispatch", "verify.wait", "verify.return")
 
 
 class OneLineProgram(batch.BatchBeaconVerifier):
